@@ -15,8 +15,9 @@ host mesh (2 data x 2 model), runs both engines on the same stream, and
     collective overhead, so tokens/sec here is NOT the TPU story — the
     footprint column is the point).
 
-Run:  PYTHONPATH=src python benchmarks/bench_calib_sharded.py
-(sets the forced device count itself; do not preset JAX_PLATFORMS/XLA_FLAGS)
+Run:  XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
+      PYTHONPATH=src python benchmarks/bench_calib_sharded.py
+(needs four devices: on the CPU, four host devices from XLA_FLAGS)
 """
 from __future__ import annotations
 
@@ -28,12 +29,11 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from repro.launch.mesh import force_host_devices  # noqa: E402
-
-force_host_devices(4)
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax  # noqa: E402
+
+if len(jax.devices()) < 4:
+    raise SystemExit("needs 4 devices; on the CPU run with XLA_FLAGS="
+                     "--xla_force_host_platform_device_count=4")
 import numpy as np  # noqa: E402
 
 from repro.configs import get_config, reduced  # noqa: E402

@@ -23,9 +23,10 @@ interconnect-free collective overhead, so decode speed here is NOT the
 TPU story — the parity and footprint columns are the point (same stance
 as benchmarks/bench_calib_sharded.py).
 
-Run:  PYTHONPATH=src python benchmarks/bench_serve_sharded.py \
+Run:  XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
+      PYTHONPATH=src python benchmarks/bench_serve_sharded.py \
           --table-out sharded_serve.md
-(sets the forced device count itself; do not preset JAX_PLATFORMS/XLA_FLAGS)
+(needs four devices: on the CPU, four host devices from XLA_FLAGS)
 """
 from __future__ import annotations
 
@@ -37,12 +38,11 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from repro.launch.mesh import force_host_devices  # noqa: E402
-
-force_host_devices(4)
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax  # noqa: E402
+
+if len(jax.devices()) < 4:
+    raise SystemExit("needs 4 devices; on the CPU run with XLA_FLAGS="
+                     "--xla_force_host_platform_device_count=4")
 import numpy as np  # noqa: E402
 
 from benchmarks.bench_serve import _zoo_cfg  # noqa: E402

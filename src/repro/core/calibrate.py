@@ -81,6 +81,7 @@ import numpy as np
 from repro.core import stats as stats_mod
 from repro.core.units import Unit
 from repro.distrib import sharding as dist_sharding
+from repro.kernels import kernel_mesh
 from repro.models import common as model_common
 
 
@@ -157,8 +158,10 @@ class CalibrationEngine:
 
         def reduce_fn(params, batch):
             taps = {}
-            # entered at trace time: taps stream in stats_dtype end-to-end
-            with model_common.tap_dtype(self.stats_dtype):
+            # entered at trace time: taps stream in stats_dtype end-to-end,
+            # and a sharded step runs its kernels once per device
+            with model_common.tap_dtype(self.stats_dtype), kernel_mesh(
+                    None if self.shard is None else self.shard.mesh):
                 model.apply(params, batch, taps=taps)
             if phase == 1:
                 return stats_mod.pass1_reduce(taps, self.units, model.cfg,

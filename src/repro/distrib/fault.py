@@ -238,4 +238,5 @@ def remesh(shape_hint=None, axis_names=("data", "model")):
         while n % a:
             a -= 1
         shape = (n // a, a)
-    return jax.make_mesh(shape, axis_names[-len(shape):])
+    from repro.launch.mesh import auto_mesh
+    return auto_mesh(shape, axis_names[-len(shape):])

@@ -263,6 +263,16 @@ def shardings_of(specs, mesh: Mesh):
                         is_leaf=lambda s: isinstance(s, P))
 
 
+def init_sharded(init, key, mesh: Mesh, *, fsdp: bool = False):
+    """``init(key)`` with every leaf born on its ``param_specs`` shards.
+
+    The init runs under jit with ``out_shardings``, so no device ever holds
+    the whole parameter tree (building on device 0 and ``device_put``-ing
+    afterwards cannot fit a model larger than one device)."""
+    specs = param_specs(jax.eval_shape(init, key), mesh, fsdp=fsdp)
+    return jax.jit(init, out_shardings=shardings_of(specs, mesh))(key)
+
+
 def batch_specs(batch_tree, mesh: Mesh):
     """Shard every batch array's leading (batch) dim over ('pod','data').
 

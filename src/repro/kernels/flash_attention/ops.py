@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import note_impl, per_device
 from repro.kernels.flash_attention import ref as _ref
 from repro.kernels.flash_attention.flash_attention import flash_attention
 
@@ -43,16 +44,17 @@ def attention(q, k, v, *, causal=True, window=None, scale=None,
     S = k.shape[1]
     if scale is None:
         scale = float(1.0 / np.sqrt(dq))
-    impl = impl or _resolve_impl(T, S, bq, bk)
+    impl = note_impl("flash_attention", impl or _resolve_impl(T, S, bq, bk))
     if impl == "ref":
         return _ref.attention(q, k, v, causal=causal, window=window,
                               scale=scale)
     if impl in ("pallas", "interpret"):
-        pbq = min(128, T) if T % min(128, T) == 0 else T
-        pbk = min(128, S) if S % min(128, S) == 0 else S
-        return flash_attention(q, k, v, causal=causal, window=window,
-                               scale=scale, bq=pbq, bk=pbk,
-                               interpret=(impl == "interpret"))
+        # the kernel pads T/S to whole 128-row blocks itself
+        kern = functools.partial(flash_attention, causal=causal,
+                                 window=window, scale=scale,
+                                 interpret=(impl == "interpret"))
+        heads = ("batch", None, "model", None)
+        return per_device(kern, (q, k, v), (heads,) * 3, heads)
     return _chunked(q, k, v, causal=causal, window=window, scale=scale,
                     bq=min(bq, T), bk=min(bk, S))
 

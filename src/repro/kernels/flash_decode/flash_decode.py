@@ -30,15 +30,15 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, acc_ref, m_ref, l_ref, *,
     q = q_ref[0, 0].astype(jnp.float32)            # (g, dq)
     k = k_ref[0, 0].astype(jnp.float32)            # (bs, dq)
     v = v_ref[0, 0].astype(jnp.float32)            # (bs, dv)
-    valid = valid_ref[0] != 0                      # (bs,)
+    valid = valid_ref[0] != 0                      # (1, bs)
 
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale  # (g, bs)
-    logits = jnp.where(valid[None, :], logits, NEG_INF)
+    logits = jnp.where(valid, logits, NEG_INF)
     m = jnp.max(logits, axis=-1, keepdims=True)      # (g, 1)
     p = jnp.exp(logits - m)
-    p = jnp.where(valid[None, :], p, 0.0)
+    p = jnp.where(valid, p, 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
     acc = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
@@ -50,7 +50,10 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, acc_ref, m_ref, l_ref, *,
 @functools.partial(jax.jit, static_argnames=("scale", "bs", "interpret"))
 def decode_attention_splits(q, k, v, valid, *, scale, bs=512,
                             interpret=False):
-    """q: (B,H,dq); k/v: (B,S,Hkv,d); valid: (B,S) int8/bool.
+    """q: (B,H,dq); k/v: (B,S,Hkv,d); valid: (B,S) int/bool.
+
+    The mask travels as (B, 1, S) int32 so its (1, 1, bs) block obeys the
+    TPU (8, 128) tiling rule (a (1, bs) block of a (B, S) array does not).
 
     Returns per-split partials (acc (B,Hkv,ns,g,dv), m, l (B,Hkv,ns,g,1)).
     """
@@ -73,7 +76,7 @@ def decode_attention_splits(q, k, v, valid, *, scale, bs=512,
             pl.BlockSpec((1, 1, g, dq), lambda b, h, s: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bs, dq), lambda b, h, s: (b, h, s, 0)),
             pl.BlockSpec((1, 1, bs, dv), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, bs), lambda b, h, s: (b, s)),
+            pl.BlockSpec((1, 1, bs), lambda b, h, s: (b, 0, s)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, 1, g, dv), lambda b, h, s: (b, h, s, 0, 0)),
@@ -86,5 +89,5 @@ def decode_attention_splits(q, k, v, valid, *, scale, bs=512,
             jax.ShapeDtypeStruct((B, Hkv, ns, g, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(qg, kt, vt, valid.astype(jnp.int8))
+    )(qg, kt, vt, valid.astype(jnp.int32)[:, None, :])
     return acc, m, l

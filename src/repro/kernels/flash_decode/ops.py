@@ -10,6 +10,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import note_impl, per_device
 from repro.kernels.flash_decode import ref as _ref
 from repro.kernels.flash_decode.flash_decode import decode_attention_splits
 
@@ -38,11 +39,17 @@ def decode_attention(q, k, v, valid, *, scale=None, bs=512, impl=None):
     S = k.shape[1]
     if scale is None:
         scale = float(1.0 / (dq ** 0.5))
-    impl = impl or _resolve_impl(S, bs)
+    impl = note_impl("flash_decode", impl or _resolve_impl(S, bs))
     if impl == "ref":
         return _ref.decode_attention(q, k, v, valid, scale)
-    acc, m, l = decode_attention_splits(q, k, v, valid, scale=scale,
-                                        bs=min(bs, S),
-                                        interpret=(impl == "interpret"))
-    o = _merge(acc, m, l)                                    # (B,Hkv,g,dv)
-    return o.reshape(B, H, -1).astype(q.dtype)
+    def kern(q, k, v, valid):
+        acc, m, l = decode_attention_splits(q, k, v, valid, scale=scale,
+                                            bs=min(bs, S),
+                                            interpret=(impl == "interpret"))
+        o = _merge(acc, m, l)                                # (B,Hkv,g,dv)
+        return o.reshape(q.shape[0], q.shape[1], -1).astype(q.dtype)
+
+    heads = ("batch", None, "model", None)
+    return per_device(kern, (q, k, v, valid),
+                      (("batch", "model", None), heads, heads,
+                       ("batch", None)), ("batch", "model", None))

@@ -37,9 +37,9 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import kernel_mesh, note_impl, per_device
 from repro.kernels.gram import ref as _ref
 from repro.kernels.gram.gram import gram as _pallas_gram
 from repro.kernels.gram.gram import gram_cross as _pallas_gram_cross
@@ -66,23 +66,26 @@ def _env_tiles(bf, bn):
 def gram(x, impl=None, *, bf=None, bn=None):
     """x: (N, F) -> {'s2': (F, F), 's1': (F,)} in fp32. Any (N, F), any
     float dtype (bf16 tiles stream at half the HBM traffic)."""
-    impl = impl or _resolve_impl()
+    impl = note_impl("gram", impl or _resolve_impl())
     if impl == "ref":
         return _ref.gram(x)
     bf, bn = _env_tiles(bf, bn)
-    return _pallas_gram(x, bf=bf, bn=bn, interpret=(impl == "interpret"))
+    return per_device(lambda x: _pallas_gram(
+        x, bf=bf, bn=bn, interpret=(impl == "interpret")), (x,),
+        ((None, None),))
 
 
 def gram_cross(x, y, impl=None, *, bf=None, bn=None):
     """x: (N, Fx), y: (N, Fy) -> {'s2': (Fx, Fy) X^T Y, 's1': (Fy,) column
     sums of Y} in fp32. The building block of the sharded gram: y is one
     shard's local column block of x."""
-    impl = impl or _resolve_impl()
+    impl = note_impl("gram", impl or _resolve_impl())
     if impl == "ref":
         return _ref.gram_cross(x, y)
     bf, bn = _env_tiles(bf, bn)
-    return _pallas_gram_cross(x, y, bf=bf, bn=bn,
-                              interpret=(impl == "interpret"))
+    return per_device(lambda x, y: _pallas_gram_cross(
+        x, y, bf=bf, bn=bn, interpret=(impl == "interpret")), (x, y),
+        ((None, None), (None, None)))
 
 
 def gram_sharded(x, mesh, *, model_axis="model", batch_axes=("data",),
@@ -133,14 +136,15 @@ def gram_sharded(x, mesh, *, model_axis="model", batch_axes=("data",),
         fn = lambda a, b: gram_cross(a, b, impl=impl, bf=bf, bn=bn)
         for _ in range(lead):
             fn = jax.vmap(fn)
-        out = fn(xl, xj)
+        with kernel_mesh(None):        # already one program per device
+            out = fn(xl, xj)
         if batch_axes:
             out = jax.lax.psum(out, batch_axes)
         return out
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=P(*lead_spec, row_spec, None),
         out_specs={"s2": P(*lead_spec, None, model_axis),
                    "s1": P(*lead_spec, model_axis)},
-        check_rep=False)(x)
+        check_vma=False)(x)

@@ -15,6 +15,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import note_impl, per_device
 from repro.kernels.wkv6 import ref as _ref
 from repro.kernels.wkv6.wkv6 import wkv6 as _pallas_wkv6
 
@@ -33,12 +34,21 @@ def _resolve_impl(T: int, chunk: int) -> str:
 def wkv6(r, k, v, w, u, state=None, *, chunk=64, impl=None):
     """r,k,v,w: (B,T,H,N); u: (H,N) -> (y, final_state (B,H,N,N))."""
     T = r.shape[1]
-    impl = impl or _resolve_impl(T, chunk)
+    impl = note_impl("wkv6", impl or _resolve_impl(T, chunk))
     if impl == "ref":
         return _ref.wkv6(r, k, v, w, u, state)
     if impl in ("pallas", "interpret"):
-        return _pallas_wkv6(r, k, v, w, u, state, chunk=min(chunk, T),
-                            interpret=(impl == "interpret"))
+        B, _, H, N = r.shape
+        if state is None:
+            state = jnp.zeros((B, H, N, N), jnp.float32)
+
+        def kern(r, k, v, w, u, state):
+            return _pallas_wkv6(r, k, v, w, u, state, chunk=min(chunk, T),
+                                interpret=(impl == "interpret"))
+        seq = ("batch", None, "model", None)
+        st = ("batch", "model", None, None)
+        return per_device(kern, (r, k, v, w, u, state),
+                          (seq,) * 4 + (("model", None), st), [seq, st])
     return _chunked(r, k, v, w, u, state, chunk=chunk)
 
 

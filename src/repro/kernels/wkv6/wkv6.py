@@ -36,11 +36,17 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sf_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     w = w_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)             # (N,)
+    u = u_ref[0].astype(jnp.float32)             # (1, N)
     S0 = S_scr[...]                              # (N, N)
 
     lw = jnp.log(jnp.maximum(w, 1e-38))
-    cum = jnp.cumsum(lw, axis=0)                 # (L, N) c_i
+    ii = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    # c_i as a lower-triangular matmul: Mosaic has no cumsum lowering
+    cum = jax.lax.dot_general(
+        (jj <= ii).astype(jnp.float32), lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)      # (L, N) c_i
     cum_prev = cum - lw                          # c_{i-1}
 
     q = r * jnp.exp(cum_prev)                    # (L, N), exp <= 1... <= e^0
@@ -50,10 +56,8 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sf_ref,
     # intra-chunk: att_ij = sum_n r_in k_jn exp(c_{i-1,n} - c_{j,n}), j < i
     dec = jnp.exp(cum_prev[:, None, :] - cum[None, :, :])   # (L, L, N)
     att = jnp.sum(r[:, None, :] * k[None, :, :] * dec, axis=-1)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
     att = jnp.where(jj < ii, att, 0.0)
-    diag = jnp.sum(r * u[None, :] * k, axis=-1)             # (L,)
+    diag = jnp.sum(r * u * k, axis=-1)                      # (L,)
     y_intra = jax.lax.dot_general(
         att, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32) + diag[:, None] * v
@@ -76,6 +80,9 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sf_ref,
 def wkv6(r, k, v, w, u, state=None, *, chunk=64, interpret=False):
     """r,k,v,w: (B,T,H,N); u: (H,N); state: (B,H,N,N) or None.
 
+    ``u`` travels as (H, 1, N) so its (1, 1, N) block obeys the TPU (8, 128)
+    tiling rule (a (1, N) block of an (H, N) array does not).
+
     Returns (y (B,T,H,N), final_state (B,H,N,N)).
     """
     B, T, H, N = r.shape
@@ -95,7 +102,7 @@ def wkv6(r, k, v, w, u, state=None, *, chunk=64, interpret=False):
             pl.BlockSpec((1, 1, L, N), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, L, N), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, L, N), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, N), lambda b, h, c: (h, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, h, c: (h, 0, 0)),
             pl.BlockSpec((1, 1, N, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -108,5 +115,5 @@ def wkv6(r, k, v, w, u, state=None, *, chunk=64, interpret=False):
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         interpret=interpret,
-    )(rt, kt, vt, wt, u, state)
+    )(rt, kt, vt, wt, u[:, None, :], state)
     return y.transpose(0, 2, 1, 3), sf

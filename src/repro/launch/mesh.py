@@ -2,49 +2,33 @@
 
 ``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
 importing this module never touches jax device state — the dry-run entry
-point must set XLA_FLAGS before any jax initialization. The same constraint
-is why ``force_host_devices`` exists: simulated multi-device runs (tests,
-docs examples, the sharded-calibration benchmark) must set
-``--xla_force_host_platform_device_count`` before the first jax import in
-the process.
+point must set XLA_FLAGS before any jax initialization. Simulated
+multi-device runs on the CPU take their device count from
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` in the environment.
 """
 from __future__ import annotations
 
-import os
 
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
 
-def force_host_devices(n: int):
-    """Simulate ``n`` devices on the host CPU platform.
-
-    Appends ``--xla_force_host_platform_device_count=n`` to ``XLA_FLAGS``.
-    Must run BEFORE jax initializes its backends (i.e. before the first
-    ``import jax`` in the process, or at least before any jax API touches
-    devices) — raises if jax is already initialized. This is how the
-    sharded-calibration tests and ``benchmarks/bench_calib_sharded.py``
-    build a >=4-device mesh on a laptop.
-    """
-    import sys
-    jx = sys.modules.get("jax")
-    try:
-        initialized = bool(jx._src.xla_bridge._backends)  # type: ignore
-    except AttributeError:
-        initialized = False
-    if initialized:
-        raise RuntimeError(
-            "force_host_devices must be called before jax initializes "
-            "its backends; set XLA_FLAGS in the environment instead")
-    flag = f"--xla_force_host_platform_device_count={n}"
-    prev = os.environ.get("XLA_FLAGS", "")
-    if flag not in prev:
-        os.environ["XLA_FLAGS"] = (prev + " " + flag).strip()
+    jax's default is Explicit axes, under which sharding-in-types rejects
+    gathers and adds over mixed-sharded operands (``ShardingTypeError``).
+    Every mesh in this repo is built here: the partitioner (and the
+    ``with_sharding_constraint`` hints in ``distrib.sharding``) places
+    intermediates."""
+    import jax
+    from jax.sharding import AxisType
+    shape = tuple(shape)
+    return jax.make_mesh(shape, tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """The fleet meshes: (data=16, model=16) per pod, x2 pods multi-pod."""
-    import jax
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def parse_shape(spec: str):
@@ -64,8 +48,7 @@ def make_mesh(shape, axes=None):
       axes: axis names; defaults to the trailing names of
         ('pod', 'data', 'model') matching ``len(shape)``.
     """
-    import jax
     if axes is None:
         axes = ("data", "model")[-len(shape):] if len(shape) <= 2 \
             else ("pod", "data", "model")
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return auto_mesh(shape, axes)

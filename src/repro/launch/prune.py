@@ -26,12 +26,15 @@ import jax
 from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.core import PruneConfig, corp_prune
 from repro.data import calib_stream
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh, parse_shape
 from repro.launch.train import resolve_config
 from repro.models import build_model
 
 
-def main():
+def main(argv=None):
+    """Run the CLI on ``argv`` (default ``sys.argv[1:]``); returns
+    ``(model, dense_params, pruned_params, pruned_cfg, report)``."""
     ap = argparse.ArgumentParser(
         description="One-shot CORP pruning over a calibration stream "
                     "(see docs/cli.md for a worked example)")
@@ -117,7 +120,7 @@ def main():
                     help="pin the gram kernel tile sizes as 'BF,BN' (e.g. "
                          "128,512) instead of the per-shape roofline "
                          "autotuner (repro.kernels.gram.autotune)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.calib_sharded and not args.mesh:
         ap.error("--calib-sharded requires --mesh")
     if args.gram_tiles:
@@ -128,6 +131,7 @@ def main():
                      f"got {args.gram_tiles!r}")
         os.environ["REPRO_GRAM_TILES"] = f"{bf},{bn}"
 
+    use_compile_cache()
     cfg = resolve_config(args.arch)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -194,6 +198,7 @@ def main():
                 lambda x: x.tolist() if hasattr(x, "tolist") else x,
                 report["units"]), f, indent=1, default=str)
         print(f"[prune] saved to {args.out}")
+    return model, params, new_params, new_cfg, report
 
 
 if __name__ == "__main__":

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import latest_step, restore_checkpoint
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.train import resolve_config
 from repro.models import build_model
 
@@ -246,8 +247,9 @@ def main():
                          "global-attention LMs only)")
     ap.add_argument("--mesh-shape", default=None,
                     help="device mesh shape 'DxM' (data x model) for "
-                         "--serve-sharded, e.g. 2x2; simulated host "
-                         "devices are forced to fill it on CPU")
+                         "--serve-sharded, e.g. 2x2; needs that many "
+                         "devices (on CPU: XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=4)")
     ap.add_argument("--serve-sharded", action="store_true",
                     help="run the shared decode step under pjit with the "
                          "slot cache model-sharded over --mesh-shape "
@@ -257,26 +259,24 @@ def main():
     if args.serve_sharded and not args.mesh_shape:
         ap.error("--serve-sharded requires --mesh-shape")
 
+    use_compile_cache()
     sharding = None
     if args.serve_sharded:
-        from repro.launch.mesh import (force_host_devices, make_mesh,
-                                       parse_shape)
+        from repro.launch.mesh import make_mesh, parse_shape
         from repro.serve import ServeSharding
-        shape = parse_shape(args.mesh_shape)
-        try:
-            # simulated-host story: fill the mesh with forced CPU devices
-            # (no-op when XLA_FLAGS already carries the flag)
-            force_host_devices(int(np.prod(shape)))
-        except RuntimeError:
-            pass   # backends already up: respect the ambient device set
-        sharding = ServeSharding(make_mesh(shape))
+        sharding = ServeSharding(make_mesh(parse_shape(args.mesh_shape)))
 
     cfg = resolve_config(args.arch)
     if args.sparsity > 0 or args.expert_sparsity > 0:
         cfg = cfg.pruned(args.sparsity, args.sparsity,
                          expert_sparsity=args.expert_sparsity)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    if sharding is None:
+        params = model.init(jax.random.PRNGKey(0))
+    else:
+        from repro.distrib.sharding import init_sharded
+        params = init_sharded(model.init, jax.random.PRNGKey(0),
+                              sharding.mesh)
     if args.ckpt_in:
         last = latest_step(args.ckpt_in)
         params, _ = restore_checkpoint(args.ckpt_in, last, params)

@@ -22,6 +22,7 @@ from repro.checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint
 from repro.configs import get_config, reduced
 from repro.data import lm_batch, vit_batch
 from repro.distrib import sharding as shard_mod
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import AdamWConfig, adamw_init, adamw_update, warmup_cosine
@@ -67,7 +68,10 @@ def train(cfg, *, steps, batch, seq, ckpt_dir=None, mesh_shape=None,
           fsdp=False, log=print):
     model = build_model(cfg)
     ocfg = AdamWConfig()
-    params = model.init(jax.random.PRNGKey(seed))
+    mesh = make_mesh(mesh_shape) if mesh_shape else None
+    params = model.init(jax.random.PRNGKey(seed)) if mesh is None else \
+        shard_mod.init_sharded(model.init, jax.random.PRNGKey(seed), mesh,
+                               fsdp=fsdp)
     opt_state = adamw_init(params, ocfg)
     step0 = 0
 
@@ -82,9 +86,7 @@ def train(cfg, *, steps, batch, seq, ckpt_dir=None, mesh_shape=None,
 
     step_fn = make_train_step(model, ocfg, peak_lr=peak_lr,
                               total_steps=steps)
-    mesh = None
-    if mesh_shape:
-        mesh = make_mesh(mesh_shape)
+    if mesh is not None:
         pspecs = shard_mod.param_specs(params, mesh, fsdp=fsdp)
         pshard = shard_mod.shardings_of(pspecs, mesh)
         oshard = shard_mod.shardings_of(
@@ -140,6 +142,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = resolve_config(args.arch)
     mesh_shape = tuple(int(x) for x in args.mesh.split("x")) if args.mesh \
         else None

@@ -283,6 +283,8 @@ def _decode_sdpa(q, k, v, valid, scale, cfg):
     if jax.default_backend() == "tpu" or os.environ.get("REPRO_DECODE_IMPL"):
         from repro.kernels.flash_decode import ops as fd_ops
         return fd_ops.decode_attention(q[:, 0], k, v, valid, scale=scale)
+    from repro.kernels import note_impl
+    note_impl("flash_decode", "xla")
     B, _, H, dq = q.shape
     Hkv = k.shape[2]
     g = H // Hkv

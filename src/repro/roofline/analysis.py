@@ -110,8 +110,6 @@ def analyze_compiled(compiled, hw: HW = HW(), *, n_devices: int = 1,
     assigned model; raw values are reported alongside).
     """
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):   # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     byac = float(cost.get("bytes accessed", 0.0))
     text = compiled.as_text()

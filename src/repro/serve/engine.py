@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import kernel_mesh
 from repro.serve import errors
 from repro.serve.cache import RecurrentSlotCache, SlotCache, cache_contract
 
@@ -210,14 +211,21 @@ class ServeEngine:
         return jnp.argmax(logits[:, -1, : self.cfg.vocab_size],
                           axis=-1).astype(jnp.int32)
 
+    def _kernel_mesh(self):
+        """Sharded steps span the mesh: their kernels run per device."""
+        return kernel_mesh(None if self.sharding is None
+                           else self.sharding.mesh)
+
     def _prefill_impl(self, params, batch, lengths):
-        logits, cache = self.model.prefill(
-            params, batch, self.max_len,
-            lengths=lengths if self.ragged_ok else None)
+        with self._kernel_mesh():
+            logits, cache = self.model.prefill(
+                params, batch, self.max_len,
+                lengths=lengths if self.ragged_ok else None)
         return self._argmax(logits), cache
 
     def _decode_impl(self, params, tok, cache):
-        logits, cache = self.model.decode_step(params, tok, cache)
+        with self._kernel_mesh():
+            logits, cache = self.model.decode_step(params, tok, cache)
         return self._argmax(logits), cache
 
     # -- slot management ----------------------------------------------------

@@ -298,12 +298,12 @@ print("OK")
 
 @pytest.mark.subprocess
 def test_serve_cli_sharded_end_to_end():
-    """--serve-sharded --mesh-shape 2x2 forces the host devices, builds
-    the mesh, and reports the per-device cache line; --serve-sharded
-    without --mesh-shape is a usage error."""
+    """--serve-sharded --mesh-shape 2x2 builds the mesh over the four
+    host devices the environment provides and reports the per-device
+    cache line; --serve-sharded without --mesh-shape is a usage error."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    env.pop("XLA_FLAGS", None)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch",

@@ -139,8 +139,6 @@ with mesh:
         params_sds, opt_sds, batch)
     compiled = lowered.compile()
 ca = compiled.cost_analysis()
-if isinstance(ca, list):   # older jax returns [dict] per computation
-    ca = ca[0] if ca else {}
 print('flops', ca.get('flops'))
 print('OK')
 """)
