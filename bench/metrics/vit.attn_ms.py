@@ -1,0 +1,12 @@
+"""Device time per forward of the leaf ops under the blocks' ``attn``
+scope (norm, projections, pads, ``flash_attention``, output projection,
+residual), over the ``jit_forward`` programs in the trace."""
+import span_reduce
+
+
+def read(run):
+    trace = span_reduce.of_run(run, __file__)
+    if not trace:
+        return None
+    return span_reduce.scope_ms(trace, "attn",
+                                span_reduce.forward_paths(run, trace))

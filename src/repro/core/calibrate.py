@@ -83,6 +83,7 @@ from repro.core.units import Unit
 from repro.distrib import sharding as dist_sharding
 from repro.kernels import kernel_mesh
 from repro.models import common as model_common
+from repro.spans import span
 
 
 class CalibrationEngine:
@@ -329,12 +330,13 @@ class CalibrationEngine:
                     fail_hook(i)
                 except Exception:       # noqa: BLE001 — simulated host loss
                     continue
-            if self.shard is not None:
-                batch = self._put_batch(batch)
-            acc = self._step(acc, params, batch)
-            n_seen += 1
-            if checkpointer is not None:
-                checkpointer.maybe_save(acc, i + 1, self.fingerprint)
+            with span("calib.batch"):
+                if self.shard is not None:
+                    batch = self._put_batch(batch)
+                acc = self._step(acc, params, batch)
+                n_seen += 1
+                if checkpointer is not None:
+                    checkpointer.maybe_save(acc, i + 1, self.fingerprint)
         if start == 0 and n_seen == 0:
             raise ValueError("every calibration batch failed")
         if checkpointer is not None:

@@ -35,6 +35,7 @@ from repro.core import ranking as rank_mod
 from repro.core import solve as solve_mod
 from repro.core import stats as stats_mod
 from repro.core.units import Unit, discover_units, get_block, set_block
+from repro.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -647,7 +648,6 @@ def corp_prune(model, params, calib_batches: Callable[[], Iterable],
       margin, candidate sizes, and hit/miss units.
     """
     import copy
-    import time
     cfg = model.cfg
     units = discover_units(cfg)
     say = progress or (lambda s: None)
@@ -663,63 +663,64 @@ def corp_prune(model, params, calib_batches: Callable[[], Iterable],
     speculate = (one_traversal and pc.attn_sparsity > 0
                  and any(u.kind in _ATTN_KINDS for u in units))
     spec_plan = spec_stats = None
-    t0 = time.time()
-    if speculate:
-        say("pass 1+2: one-traversal speculative statistics")
-        p1, spec_plan, spec_stats = _speculative_pass(
-            model, units, params, calib_batches(), pc,
-            spec_margin=spec_margin, mesh=mesh, stats_dtype=stats_dtype,
-            ckpt_dir=ckpt_dir, ckpt_every=ckpt_every)
-    else:
-        say("pass 1: ranking/MLP statistics")
-        engine1 = calib_mod.CalibrationEngine(model, units, phase=1,
-                                              mesh=mesh,
-                                              stats_dtype=stats_dtype)
-        p1 = engine1.run(params, calib_batches(),
-                         checkpointer=_checkpointer(ckpt_dir, "pass1",
-                                                    ckpt_every))
-    report["timing"]["pass1"] = time.time() - t0
+    with span("prune.pass1") as stage:
+        if speculate:
+            say("pass 1+2: one-traversal speculative statistics")
+            p1, spec_plan, spec_stats = _speculative_pass(
+                model, units, params, calib_batches(), pc,
+                spec_margin=spec_margin, mesh=mesh, stats_dtype=stats_dtype,
+                ckpt_dir=ckpt_dir, ckpt_every=ckpt_every)
+        else:
+            say("pass 1: ranking/MLP statistics")
+            engine1 = calib_mod.CalibrationEngine(model, units, phase=1,
+                                                  mesh=mesh,
+                                                  stats_dtype=stats_dtype)
+            p1 = engine1.run(params, calib_batches(),
+                             checkpointer=_checkpointer(ckpt_dir, "pass1",
+                                                        ckpt_every))
+    report["timing"]["pass1"] = stage.seconds
 
     # --- ranking ----------------------------------------------------------
-    t0 = time.time()
     plan = {}       # unit.name -> (keep, prune) np arrays
-    for u in units:
-        st = p1[u.name]
-        if u.kind in ("mlp", "rwkv_mlp", "moe", "mamba"):
-            if u.kind == "mamba" and not pc.include_mamba:
-                continue
-            if pc.mlp_sparsity <= 0:
-                continue
-            blockp = get_block(params, u)
-            if u.shared_expert:
-                blockp = blockp["shared"]
-            w2 = blockp["wv" if u.kind == "rwkv_mlp"
-                        else "out_proj" if u.kind == "mamba" else "wd"]
-            keep_n = _keep_count(u.d_hidden if u.kind != "mamba"
-                                 else cfg.mamba.expand * cfg.d_model,
-                                 pc.mlp_sparsity, pc.round_to)
-            keep, prune = rank_mod.rank_mlp(st, np.asarray(w2), keep_n,
-                                            pc.rank_policy)
-            plan[u.name] = (keep, prune)
-        elif u.kind in _ATTN_KINDS:
-            if pc.attn_sparsity <= 0:
-                continue
-            full = st["rank"].shape[-1]       # dims (cls1) or pairs (cls2/3)
-            keep, prune = rank_mod.rank_attn(st, _attn_keep_n(u, full, pc))
-            plan[u.name] = (keep, prune)
-    e_plan = _moe_expert_plan(units, p1, cfg, pc)
-    report["timing"]["rank"] = time.time() - t0
+    with span("prune.rank") as stage:
+        for u in units:
+            st = p1[u.name]
+            if u.kind in ("mlp", "rwkv_mlp", "moe", "mamba"):
+                if u.kind == "mamba" and not pc.include_mamba:
+                    continue
+                if pc.mlp_sparsity <= 0:
+                    continue
+                blockp = get_block(params, u)
+                if u.shared_expert:
+                    blockp = blockp["shared"]
+                w2 = blockp["wv" if u.kind == "rwkv_mlp"
+                            else "out_proj" if u.kind == "mamba" else "wd"]
+                keep_n = _keep_count(u.d_hidden if u.kind != "mamba"
+                                     else cfg.mamba.expand * cfg.d_model,
+                                     pc.mlp_sparsity, pc.round_to)
+                keep, prune = rank_mod.rank_mlp(st, np.asarray(w2), keep_n,
+                                                pc.rank_policy)
+                plan[u.name] = (keep, prune)
+            elif u.kind in _ATTN_KINDS:
+                if pc.attn_sparsity <= 0:
+                    continue
+                full = st["rank"].shape[-1]   # dims (cls1) or pairs (cls2/3)
+                keep, prune = rank_mod.rank_attn(st,
+                                                 _attn_keep_n(u, full, pc))
+                plan[u.name] = (keep, prune)
+        e_plan = _moe_expert_plan(units, p1, cfg, pc)
+    report["timing"]["rank"] = stage.seconds
 
     # --- pass 2: attention compensation statistics -------------------------
     attn_plan = {u.name: plan[u.name] for u in units
                  if u.kind in _ATTN_KINDS and u.name in plan}
     p2 = {}
     if attn_plan:
-        t0 = time.time()
-        p2, misses = _resolve_attn_pass2(
-            model, units, params, calib_batches, attn_plan, spec_plan,
-            spec_stats, mesh=mesh, stats_dtype=stats_dtype,
-            ckpt_dir=ckpt_dir, ckpt_every=ckpt_every, say=say)
+        with span("prune.pass2") as stage:
+            p2, misses = _resolve_attn_pass2(
+                model, units, params, calib_batches, attn_plan, spec_plan,
+                spec_stats, mesh=mesh, stats_dtype=stats_dtype,
+                ckpt_dir=ckpt_dir, ckpt_every=ckpt_every, say=say)
         if speculate:
             report["speculative"] = {
                 "margin": spec_margin,
@@ -727,41 +728,41 @@ def corp_prune(model, params, calib_batches: Callable[[], Iterable],
                                for k, v in spec_plan.items()},
                 "hits": sorted(set(attn_plan) - set(misses)),
                 "misses": sorted(misses)}
-        report["timing"]["pass2"] = time.time() - t0
+        report["timing"]["pass2"] = stage.seconds
 
     # --- fold -------------------------------------------------------------
-    t0 = time.time()
     say("closed-form compensation + fold")
-    new_params = copy.deepcopy(jax.device_get(params))
-    for u in units:
-        if u.name not in plan and u.name not in e_plan:
-            continue
-        block = get_block(new_params, u)
-        if u.name in plan:
-            keep, prune = plan[u.name]
-            if u.kind in ("mlp", "rwkv_mlp"):
-                tgt = block["shared"] if u.shared_expert else block
-                folded = _fold_mlp_block(tgt, p1[u.name], u, pc, keep,
-                                         prune, report["units"])
-                if u.shared_expert:
-                    block = dict(block, shared=folded)
+    with span("prune.fold") as stage:
+        new_params = copy.deepcopy(jax.device_get(params))
+        for u in units:
+            if u.name not in plan and u.name not in e_plan:
+                continue
+            block = get_block(new_params, u)
+            if u.name in plan:
+                keep, prune = plan[u.name]
+                if u.kind in ("mlp", "rwkv_mlp"):
+                    tgt = block["shared"] if u.shared_expert else block
+                    folded = _fold_mlp_block(tgt, p1[u.name], u, pc, keep,
+                                             prune, report["units"])
+                    if u.shared_expert:
+                        block = dict(block, shared=folded)
+                    else:
+                        block = folded
+                elif u.kind == "moe":
+                    block = _fold_moe_block(block, p1[u.name], u, pc, keep,
+                                            prune, report["units"])
+                elif u.kind == "mamba":
+                    block = _fold_mamba_block(block, p1[u.name], u, pc,
+                                              keep, prune, report["units"])
                 else:
-                    block = folded
-            elif u.kind == "moe":
-                block = _fold_moe_block(block, p1[u.name], u, pc, keep,
-                                        prune, report["units"])
-            elif u.kind == "mamba":
-                block = _fold_mamba_block(block, p1[u.name], u, pc, keep,
-                                          prune, report["units"])
-            else:
-                block = _fold_attn_block(block, p2[u.name], u, cfg, pc,
-                                         keep, prune, report["units"])
-        if u.name in e_plan:
-            ek, ep = e_plan[u.name]
-            block = _fold_moe_experts(block, p1[u.name], u, pc, ek, ep,
-                                      report["units"])
-        set_block(new_params, u, block)
-    report["timing"]["fold"] = time.time() - t0
+                    block = _fold_attn_block(block, p2[u.name], u, cfg, pc,
+                                             keep, prune, report["units"])
+            if u.name in e_plan:
+                ek, ep = e_plan[u.name]
+                block = _fold_moe_experts(block, p1[u.name], u, pc, ek, ep,
+                                          report["units"])
+            set_block(new_params, u, block)
+    report["timing"]["fold"] = stage.seconds
     report["plan_sizes"] = {k: v[0].shape for k, v in plan.items()}
     report["plan_sizes"].update(
         {k + "/experts": v[0].shape for k, v in e_plan.items()})

@@ -19,7 +19,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import time
 
 import jax
 
@@ -30,6 +29,7 @@ from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh, parse_shape
 from repro.launch.train import resolve_config
 from repro.models import build_model
+from repro.spans import span
 
 
 def main(argv=None):
@@ -158,22 +158,21 @@ def main(argv=None):
                           batch=args.calib_batch, seq=args.calib_seq)
 
     ctx = make_mesh(parse_shape(args.mesh)) if args.mesh else None
-    t0 = time.time()
     kw = dict(progress=print, ckpt_dir=args.calib_ckpt,
               ckpt_every=args.calib_ckpt_every,
               mesh=ctx if args.calib_sharded else None,
               stats_dtype=args.stats_dtype,
               one_traversal=args.one_traversal,
               spec_margin=args.spec_margin)
-    if ctx is not None:
-        with ctx:
+    with span("prune") as whole:
+        if ctx is not None:
+            with ctx:
+                new_params, new_cfg, report = corp_prune(model, params,
+                                                         stream, pc, **kw)
+        else:
             new_params, new_cfg, report = corp_prune(model, params, stream,
                                                      pc, **kw)
-    else:
-        new_params, new_cfg, report = corp_prune(model, params, stream, pc,
-                                                 **kw)
-    dt = time.time() - t0
-    print(f"[prune] done in {dt:.1f}s; "
+    print(f"[prune] done in {whole.seconds:.1f}s; "
           f"d_ff {cfg.d_ff} -> {new_cfg.eff_d_ff}, "
           f"qk {cfg.qk_full} -> {new_cfg.eff_qk}"
           + (f", experts {cfg.moe.num_experts} -> "

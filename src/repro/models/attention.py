@@ -243,8 +243,9 @@ def decode_attn(p, x, cache, cfg, kind):
     q, k_new, v_new = _project_qkv(p, x, cfg, positions, kind, None)
     S = cache["k"].shape[1]
     slot = jnp.mod(pos, S) if kind == "swa" else pos
-    k = _scatter_time(cache["k"], k_new[:, 0], slot)
-    v = _scatter_time(cache["v"], v_new[:, 0], slot)
+    with jax.named_scope("kv_write"):
+        k = _scatter_time(cache["k"], k_new[:, 0], slot)
+        v = _scatter_time(cache["v"], v_new[:, 0], slot)
     if kind == "swa":
         abs_pos = _scatter_time(cache["abs_pos"][..., None],
                                 pos[:, None], slot)[..., 0]

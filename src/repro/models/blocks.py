@@ -3,6 +3,12 @@
 A block's mixer is one of 'attn' | 'swa' | 'mamba' | 'rwkv'; its MLP is dense
 or MoE (per ``cfg.layer_is_moe``). Decoder blocks in enc-dec models carry an
 extra cross-attention sub-layer.
+
+The two halves run under ``jax.named_scope``: the mixer half (norm, mixer,
+residual) under ``attn`` for attention mixers and under the mixer's kind
+otherwise, the MLP half under ``mlp``. The names ride in every op's
+metadata, so a profile splits a layer's device time between them
+(``repro.spans.NAMES``).
 """
 from __future__ import annotations
 
@@ -44,21 +50,26 @@ def init_block(key, cfg, kind: str, is_moe: bool, *, cross: bool = False,
     return p
 
 
+def _mixer_scope(kind: str) -> str:
+    return "attn" if kind in ("attn", "swa") else kind
+
+
 def apply_block(p, x, cfg, kind: str, is_moe: bool, *, positions,
                 taps=None, mem=None, mask_kind="causal", train=False):
     """Full-sequence block. Returns (x, aux_loss)."""
     t = {} if taps is not None else None
     aux = jnp.zeros((), jnp.float32)
-    h = apply_norm(p["ln1"], x, cfg)
-    if kind in ("attn", "swa"):
-        y, _ = attn_mod.apply_attn(p["mixer"], h, cfg, kind,
-                                   positions=positions, taps=t,
-                                   mask_kind=mask_kind)
-    elif kind == "mamba":
-        y, _ = ssm_mod.apply_mamba(p["mixer"], h, cfg, taps=t)
-    else:  # rwkv
-        y, _ = ssm_mod.apply_rwkv_time(p["mixer"], h, cfg, taps=t)
-    x = x + y
+    with jax.named_scope(_mixer_scope(kind)):
+        h = apply_norm(p["ln1"], x, cfg)
+        if kind in ("attn", "swa"):
+            y, _ = attn_mod.apply_attn(p["mixer"], h, cfg, kind,
+                                       positions=positions, taps=t,
+                                       mask_kind=mask_kind)
+        elif kind == "mamba":
+            y, _ = ssm_mod.apply_mamba(p["mixer"], h, cfg, taps=t)
+        else:  # rwkv
+            y, _ = ssm_mod.apply_rwkv_time(p["mixer"], h, cfg, taps=t)
+        x = x + y
     if "cross" in p and mem is not None:
         tc = {} if taps is not None else None
         h = apply_norm(p["ln_cross"], x, cfg)
@@ -67,14 +78,16 @@ def apply_block(p, x, cfg, kind: str, is_moe: bool, *, positions,
             for kname, vv in tc.items():
                 t["cross_" + kname] = vv
         x = x + yc
-    h = apply_norm(p["ln2"], x, cfg)
-    if kind == "rwkv":
-        y, _ = ssm_mod.apply_rwkv_channel(p["mlp"], h, cfg, taps=t)
-    elif is_moe:
-        y, aux = mlp_mod.apply_moe(p["mlp"], h, cfg, taps=t, train=train)
-    else:
-        y = mlp_mod.apply_mlp(p["mlp"], h, cfg, taps=t)
-    x = x + y
+    with jax.named_scope("mlp"):
+        h = apply_norm(p["ln2"], x, cfg)
+        if kind == "rwkv":
+            y, _ = ssm_mod.apply_rwkv_channel(p["mlp"], h, cfg, taps=t)
+        elif is_moe:
+            y, aux = mlp_mod.apply_moe(p["mlp"], h, cfg, taps=t,
+                                       train=train)
+        else:
+            y = mlp_mod.apply_mlp(p["mlp"], h, cfg, taps=t)
+        x = x + y
     if taps is not None:
         merge_taps(taps, t, "")
     return x, aux
@@ -93,27 +106,30 @@ def init_block_cache(cfg, kind: str, batch: int, max_len: int):
 def decode_block(p, x, cache, cfg, kind: str, is_moe: bool, *,
                  cross_cache=None):
     """One-token decode. x: (B,1,D). Returns (x, new_cache)."""
-    h = apply_norm(p["ln1"], x, cfg)
-    if kind in ("attn", "swa"):
-        y, new_cache = attn_mod.decode_attn(p["mixer"], h, cache, cfg, kind)
-    elif kind == "mamba":
-        y, ms = ssm_mod.apply_mamba(p["mixer"], h, cfg, state=cache)
-        new_cache = ms
-    else:  # rwkv
-        y, ts = ssm_mod.apply_rwkv_time(p["mixer"], h, cfg,
-                                        state=cache["time"])
-        new_cache = dict(cache, time=ts)
-    x = x + y
+    with jax.named_scope(_mixer_scope(kind)):
+        h = apply_norm(p["ln1"], x, cfg)
+        if kind in ("attn", "swa"):
+            y, new_cache = attn_mod.decode_attn(p["mixer"], h, cache, cfg,
+                                                kind)
+        elif kind == "mamba":
+            y, ms = ssm_mod.apply_mamba(p["mixer"], h, cfg, state=cache)
+            new_cache = ms
+        else:  # rwkv
+            y, ts = ssm_mod.apply_rwkv_time(p["mixer"], h, cfg,
+                                            state=cache["time"])
+            new_cache = dict(cache, time=ts)
+        x = x + y
     if "cross" in p and cross_cache is not None:
         h = apply_norm(p["ln_cross"], x, cfg)
         x = x + attn_mod.decode_cross_attn(p["cross"], h, cross_cache, cfg)
-    h = apply_norm(p["ln2"], x, cfg)
-    if kind == "rwkv":
-        y, cs = ssm_mod.apply_rwkv_channel(p["mlp"], h, cfg,
-                                           state=cache["channel"])
-        new_cache = dict(new_cache, channel=cs)
-    elif is_moe:
-        y, _ = mlp_mod.apply_moe(p["mlp"], h, cfg)
-    else:
-        y = mlp_mod.apply_mlp(p["mlp"], h, cfg)
-    return x + y, new_cache
+    with jax.named_scope("mlp"):
+        h = apply_norm(p["ln2"], x, cfg)
+        if kind == "rwkv":
+            y, cs = ssm_mod.apply_rwkv_channel(p["mlp"], h, cfg,
+                                               state=cache["channel"])
+            new_cache = dict(new_cache, channel=cs)
+        elif is_moe:
+            y, _ = mlp_mod.apply_moe(p["mlp"], h, cfg)
+        else:
+            y = mlp_mod.apply_mlp(p["mlp"], h, cfg)
+        return x + y, new_cache

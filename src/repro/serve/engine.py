@@ -53,6 +53,7 @@ import numpy as np
 from repro.kernels import kernel_mesh
 from repro.serve import errors
 from repro.serve.cache import RecurrentSlotCache, SlotCache, cache_contract
+from repro.spans import span
 
 
 @dataclasses.dataclass
@@ -381,11 +382,14 @@ class ServeEngine:
                 toks[0, :L0] = req.tokens[:L0]
             else:
                 toks = np.asarray(req.tokens[:L0], np.int32)[None]
-            batch = {"tokens": jnp.asarray(toks)}
-            if self.cfg.family == "encdec":
-                batch["frames"] = jnp.asarray(np.asarray(req.frames))[None]
-            nxt, st.local = self._prefill(self.params, batch,
-                                          jnp.asarray([L0], jnp.int32))
+            with span("engine.prefill", rid=req.rid, tokens=L0,
+                      bucket=toks.shape[1]):
+                batch = {"tokens": jnp.asarray(toks)}
+                if self.cfg.family == "encdec":
+                    batch["frames"] = jnp.asarray(
+                        np.asarray(req.frames))[None]
+                nxt, st.local = self._prefill(self.params, batch,
+                                              jnp.asarray([L0], jnp.int32))
             self.stats[f"prefill_b{self._stat_bucket(L0)}"] += 1
             st.consumed = L0
             budget -= L0
@@ -407,7 +411,8 @@ class ServeEngine:
         if st.consumed < P:
             self.stats["chunk_steps"] += 1
             return False
-        st.first = int(nxt[0])
+        with span("engine.prefill.wait"):
+            st.first = int(nxt[0])
         self._install(slot, st)
         return True
 
@@ -415,18 +420,18 @@ class ServeEngine:
         """Prefill complete: insert into the prefix cache, scatter the
         local cache into the slot lane, and make the slot decode-eligible
         with its first generated token."""
-        if st.prefix_cache is not None:
-            from repro.serve.cache import cache_bytes
-            st.prefix_cache.insert(st.req.tokens, st.local,
-                                   cache_bytes(st.local))
-        s = self.slots[slot]
-        now = self._now()
-        s.out = [st.first]
-        s.remaining = st.req.gen - 1
-        s.t_admit = s.t_first = now
-        self.tokens[slot] = st.first
-        self.slotcache.write_slot(st.local, slot)
-        s.pending = None
+        with span("engine.install", slot=slot):
+            if st.prefix_cache is not None:
+                from repro.serve.cache import cache_bytes
+                st.prefix_cache.insert(st.req.tokens, st.local,
+                                       cache_bytes(st.local))
+            s = self.slots[slot]
+            s.out = [st.first]
+            s.remaining = st.req.gen - 1
+            s.t_admit = s.t_first = self._now()
+            self.tokens[slot] = st.first
+            self.slotcache.write_slot(st.local, slot)
+            s.pending = None
 
     def admit(self, req: Request, slot: int, prefix_cache=None):
         """Prefill ``req`` and install it into ``slot`` — the atomic
@@ -444,29 +449,32 @@ class ServeEngine:
 
     def decode_step(self):
         """One shared decode step over every slot; returns retired slots."""
-        nxt, cache = self._decode(self.params,
-                                  jnp.asarray(self.tokens[:, None]),
-                                  self.slotcache.cache)
-        self.slotcache.cache = cache
-        nxt = np.asarray(nxt)
         active = self.decoding_count()
-        self.stats["decode_steps"] += 1
-        self.stats["decode_lanes"] += active
-        self.stats["max_concurrent"] = max(self.stats["max_concurrent"],
-                                           active)
-        retired = []
-        for i, s in enumerate(self.slots):
-            # PREFILLING slots skip decode lanes: their lane computed
-            # garbage (stale token over a stale cache row, like a free
-            # slot's) and the install scatter overwrites the row wholesale
-            if s.free or s.pending is not None:
-                continue
-            s.out.append(int(nxt[i]))
-            self.tokens[i] = nxt[i]
-            s.remaining -= 1
-            if s.remaining == 0:
-                retired.append(i)
-        return retired
+        with span("engine.decode", step=self.stats["decode_steps"],
+                  lanes=active):
+            nxt, cache = self._decode(self.params,
+                                      jnp.asarray(self.tokens[:, None]),
+                                      self.slotcache.cache)
+            self.slotcache.cache = cache
+            with span("engine.decode.wait"):
+                nxt = np.asarray(nxt)
+            self.stats["decode_steps"] += 1
+            self.stats["decode_lanes"] += active
+            self.stats["max_concurrent"] = max(self.stats["max_concurrent"],
+                                               active)
+            retired = []
+            for i, s in enumerate(self.slots):
+                # PREFILLING slots skip decode lanes: their lane computed
+                # garbage (stale token over a stale cache row, like a free
+                # slot's) and the install scatter overwrites the row wholesale
+                if s.free or s.pending is not None:
+                    continue
+                s.out.append(int(nxt[i]))
+                self.tokens[i] = nxt[i]
+                s.remaining -= 1
+                if s.remaining == 0:
+                    retired.append(i)
+            return retired
 
     def retire(self, slot: int) -> Completion:
         """Free ``slot`` and return its finished request's Completion.

@@ -61,6 +61,7 @@ from repro.serve import errors
 from repro.serve.engine import Request
 from repro.serve.queue import Overloaded, Status, TERMINAL
 from repro.serve.scheduler import Scheduler
+from repro.spans import span
 
 
 @dataclasses.dataclass
@@ -155,13 +156,15 @@ class ServeFrontend:
         ``Overloaded`` when the queue is full."""
         if req.rid in self.handles:
             raise ValueError(f"duplicate rid {req.rid}")
-        h = Handle(req=req, t_submit=self.clock())
-        self.handles[req.rid] = h
-        if not len(self.queue) and self.engine.free_slots():
-            self._admit(h, self.engine.free_slots()[0])
-        elif not self.queue.push(h):
-            h.result = Overloaded(rid=req.rid, queue_depth=self.queue.depth)
-            self._finish(h, Status.REJECTED)
+        with span("frontend.submit", rid=req.rid):
+            h = Handle(req=req, t_submit=self.clock())
+            self.handles[req.rid] = h
+            if not len(self.queue) and self.engine.free_slots():
+                self._admit(h, self.engine.free_slots()[0])
+            elif not self.queue.push(h):
+                h.result = Overloaded(rid=req.rid,
+                                      queue_depth=self.queue.depth)
+                self._finish(h, Status.REJECTED)
         return h
 
     def cancel(self, rid: int) -> bool:
@@ -186,6 +189,10 @@ class ServeFrontend:
 
     def step(self) -> bool:
         """One engine iteration; returns True while work remains."""
+        with span("frontend.step"):
+            return self._step()
+
+    def _step(self) -> bool:
         now = self.clock()
         # 0. fleet failures: a routing engine surfaces requests whose
         #    replica died with no survivor to re-dispatch to
@@ -216,13 +223,14 @@ class ServeFrontend:
         # 5. one shared decode step; stream tokens out, retire the finished
         if self.scheduler.should_decode():
             retired = self.engine.decode_step()
-            for slot, h in self._by_slot.items():
-                h.tokens = [int(t) for t in self.engine.slots[slot].out]
-            for slot in retired:
-                h = self._by_slot.pop(slot)
-                comp = self.engine.retire(slot)
-                h.tokens = [int(t) for t in comp.tokens]
-                self._finish(h, Status.DONE)
+            with span("frontend.stream", lanes=len(self._by_slot)):
+                for slot, h in self._by_slot.items():
+                    h.tokens = [int(t) for t in self.engine.slots[slot].out]
+                for slot in retired:
+                    h = self._by_slot.pop(slot)
+                    comp = self.engine.retire(slot)
+                    h.tokens = [int(t) for t in comp.tokens]
+                    self._finish(h, Status.DONE)
             self._reap_failed()       # decode may have killed a replica
         return bool(self._by_slot) or len(self.queue) > 0
 

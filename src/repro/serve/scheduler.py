@@ -32,15 +32,16 @@ decode-gap p99 win).
 the engine's one-shot ``admit`` to completion, byte-for-byte the pre-PR-10
 behavior.
 
-Pure Python, no jax — like the queue policies it absorbed, this module is
-scheduling state the property suite (``tests/test_serve_properties.py``)
-drives against a slot-state oracle.
+Pure Python, no jax arrays — like the queue policies it absorbed, this
+module is scheduling state the property suite
+(``tests/test_serve_properties.py``) drives against a slot-state oracle.
 """
 from __future__ import annotations
 
 from typing import List, Optional
 
 from repro.serve import errors
+from repro.spans import span
 
 
 class AdmissionQueue:
@@ -174,14 +175,17 @@ class Scheduler:
         (the first token exists on the slot). False marks the slot
         PREFILLING: later ``advance`` calls consume the rest of the
         prompt, one chunk per call."""
-        if not self.chunked:
-            self.engine.admit(req, slot, prefix_cache=self.prefix_cache)
-            return True
-        self.engine.begin_admit(req, slot, prefix_cache=self.prefix_cache)
-        if self.engine.continue_admit(slot, self.prefill_chunk):
-            return True
-        self._prefilling.add(slot)
-        return False
+        with span("sched.admit", rid=req.rid, slot=slot,
+                  tokens=len(req.tokens)):
+            if not self.chunked:
+                self.engine.admit(req, slot, prefix_cache=self.prefix_cache)
+                return True
+            self.engine.begin_admit(req, slot,
+                                    prefix_cache=self.prefix_cache)
+            if self.engine.continue_admit(slot, self.prefill_chunk):
+                return True
+            self._prefilling.add(slot)
+            return False
 
     def advance(self) -> List[int]:
         """One chunk of prefill for every PREFILLING slot; returns the
@@ -189,10 +193,13 @@ class Scheduler:
         token on the slot). Call once per engine iteration — the per-slot
         budget discipline (at most ``prefill_chunk`` tokens per iteration)
         is exactly one ``continue_admit`` per slot per call."""
+        if not self._prefilling:
+            return []
         done = []
-        for slot in sorted(self._prefilling):
-            if self.engine.continue_admit(slot, self.prefill_chunk):
-                done.append(slot)
+        with span("sched.advance"):
+            for slot in sorted(self._prefilling):
+                if self.engine.continue_admit(slot, self.prefill_chunk):
+                    done.append(slot)
         self._prefilling.difference_update(done)
         return done
 
