@@ -30,9 +30,10 @@ from jax.sharding import PartitionSpec as P
 RESOLVED: collections.Counter = collections.Counter()
 
 
-def note_impl(op: str, impl: str) -> str:
-    """Record that ``op`` resolved to ``impl``; returns ``impl``."""
-    RESOLVED[(op, impl)] += 1
+def note_impl(op: str, impl: str, n: int = 1) -> str:
+    """Record that ``op`` resolved to ``impl`` (``n`` times: one trace of a
+    scanned layer stands for every layer of its stack); returns ``impl``."""
+    RESOLVED[(op, impl)] += n
     return impl
 
 

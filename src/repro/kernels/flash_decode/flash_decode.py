@@ -5,14 +5,23 @@ so the kernel's job is to read each cache block from HBM exactly once at
 full bandwidth while parallelizing over the sequence axis (one q token
 gives no q-parallelism — FlashDecoding's split-K trick):
 
-  phase 1 (this kernel): grid (B, Hkv, S/bs) — each program reduces one KV
-    block to a partial (acc, m, l) triple for all g = H/Hkv query heads of
-    its kv head, written per split.
+  phase 1 (this kernel): grid (B, S/bs) — each program reduces one block
+    of one lane's cache rows to a partial (acc, m, l) triple for all H
+    query heads, written per split.
   phase 2 (tiny jnp epilogue in ops.py): logsumexp-merge the S/bs partials.
 
-VMEM per program: the (bs, d) K/V tiles + (g, dv) accumulators — the cache
-never lands in VMEM twice, and splits proceed in parallel across the
-sequence (unlike the fwd flash kernel's sequential kv grid walk).
+The kernel reads the cache where it is stored. K and V are stacks
+``(L, B, S, Hkv*d)`` — every layer of a scanned segment, kv heads folded
+into lanes — and the layer index arrives as a scalar-prefetch operand that
+the block index maps read, so no slice, transpose or copy of the cache is
+made before the call. Folded heads keep the blocks lane-dense (Hkv*dq
+lanes of K, Hkv*dv of V) where a per-head ``(S, d)`` tile with d = 64
+would pad every row to 128 lanes.
+
+Each program serves every head of its lane: q arrives block-diagonal
+``(H, Hkv*dq)`` (row h holds its query in its kv head's lanes, exact zeros
+elsewhere), so one matmul gives every head's logits against only its own
+kv head; P·V runs once per kv head with the other groups' rows zeroed.
 """
 from __future__ import annotations
 
@@ -21,73 +30,90 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, acc_ref, m_ref, l_ref, *,
-                   scale, g, bs):
-    q = q_ref[0, 0].astype(jnp.float32)            # (g, dq)
-    k = k_ref[0, 0].astype(jnp.float32)            # (bs, dq)
-    v = v_ref[0, 0].astype(jnp.float32)            # (bs, dv)
-    valid = valid_ref[0] != 0                      # (1, bs)
+def _decode_kernel(layer_ref, q_ref, k_ref, v_ref, valid_ref, acc_ref, m_ref,
+                   l_ref, *, scale, g, dv):
+    del layer_ref                                  # read by the index maps
+    q = q_ref[...]                                 # (H, Hkv*dq) block-diag
+    k = k_ref[...]                                 # (bs, Hkv*dq)
+    if q.dtype != k.dtype:
+        q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+    valid = valid_ref[...] != 0                    # (1, bs)
 
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # (g, bs)
+        preferred_element_type=jnp.float32) * scale  # (H, bs)
     logits = jnp.where(valid, logits, NEG_INF)
-    m = jnp.max(logits, axis=-1, keepdims=True)      # (g, 1)
+    m = jnp.max(logits, axis=-1, keepdims=True)      # (H, 1)
     p = jnp.exp(logits - m)
     p = jnp.where(valid, p, 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    acc = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    acc_ref[0, 0, 0] = acc                           # (g, dv)
-    m_ref[0, 0, 0] = m
-    l_ref[0, 0, 0] = l
+    head = jax.lax.broadcasted_iota(jnp.int32, p.shape, 0) // g
+    acc = jnp.zeros((p.shape[0], dv), jnp.float32)
+    for n in range(v_ref.shape[-1] // dv):         # kv heads, static
+        vn = v_ref[:, pl.ds(n * dv, dv)].astype(jnp.float32)  # (bs, dv)
+        acc += jax.lax.dot_general(jnp.where(head == n, p, 0.0), vn,
+                                   (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+    acc_ref[...] = acc                             # (H, dv)
+    m_ref[...] = m
+    l_ref[...] = l
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "bs", "interpret"))
-def decode_attention_splits(q, k, v, valid, *, scale, bs=512,
+def decode_attention_splits(q, k, v, valid, layer, *, scale, bs=512,
                             interpret=False):
-    """q: (B,H,dq); k/v: (B,S,Hkv,d); valid: (B,S) int/bool.
+    """q: (B,H,dq); k: (L,B,S,Hkv*dq), v: (L,B,S,Hkv*dv) — a stacked cache
+    read in place at ``layer`` (int32 scalar); valid: (B,S) int/bool.
 
-    The mask travels as (B, 1, S) int32 so its (1, 1, bs) block obeys the
+    The mask travels as (B, 1, S) int32 so its (1, bs) block obeys the
     TPU (8, 128) tiling rule (a (1, bs) block of a (B, S) array does not).
 
-    Returns per-split partials (acc (B,Hkv,ns,g,dv), m, l (B,Hkv,ns,g,1)).
+    Returns per-split partials (acc (B,ns,H,dv), m, l (B,ns,H,1)).
     """
     B, H, dq = q.shape
-    S, Hkv = k.shape[1], k.shape[2]
-    dv = v.shape[-1]
+    S, Fk = k.shape[2], k.shape[3]
+    Hkv = Fk // dq
+    assert Hkv * dq == Fk and H % Hkv == 0, (q.shape, k.shape)
+    dv = v.shape[-1] // Hkv
     g = H // Hkv
     bs = min(bs, S)
     assert S % bs == 0
     ns = S // bs
 
-    qg = q.reshape(B, Hkv, g, dq)
-    kt = k.transpose(0, 2, 1, 3)                     # (B,Hkv,S,dq)
-    vt = v.transpose(0, 2, 1, 3)
-    kernel = functools.partial(_decode_kernel, scale=scale, g=g, bs=bs)
-    acc, m, l = pl.pallas_call(
-        kernel,
-        grid=(B, Hkv, ns),
+    eye = jnp.eye(Hkv, dtype=bool)[None, :, None, :, None]
+    qbd = jnp.where(eye, q.reshape(B, Hkv, g, 1, dq), 0).reshape(B, H, Fk)
+    kernel = functools.partial(_decode_kernel, scale=scale, g=g, dv=dv)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, ns),
         in_specs=[
-            pl.BlockSpec((1, 1, g, dq), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, dq), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, bs, dv), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, bs), lambda b, h, s: (b, 0, s)),
+            pl.BlockSpec((None, H, Fk), lambda b, s, lyr: (b, 0, 0)),
+            pl.BlockSpec((None, None, bs, Fk),
+                         lambda b, s, lyr: (lyr[0], b, s, 0)),
+            pl.BlockSpec((None, None, bs, v.shape[-1]),
+                         lambda b, s, lyr: (lyr[0], b, s, 0)),
+            pl.BlockSpec((None, 1, bs), lambda b, s, lyr: (b, 0, s)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, g, dv), lambda b, h, s: (b, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, g, 1), lambda b, h, s: (b, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, g, 1), lambda b, h, s: (b, h, s, 0, 0)),
+            pl.BlockSpec((None, None, H, dv), lambda b, s, lyr: (b, s, 0, 0)),
+            pl.BlockSpec((None, None, H, 1), lambda b, s, lyr: (b, s, 0, 0)),
+            pl.BlockSpec((None, None, H, 1), lambda b, s, lyr: (b, s, 0, 0)),
         ],
+    )
+    acc, m, l = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, ns, g, dv), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, ns, g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, ns, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, ns, H, dv), jnp.float32),
+            jax.ShapeDtypeStruct((B, ns, H, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, ns, H, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(qg, kt, vt, valid.astype(jnp.int32)[:, None, :])
+    )(jnp.asarray(layer, jnp.int32).reshape(1), qbd, k, v,
+      valid.astype(jnp.int32)[:, None, :])
     return acc, m, l

@@ -141,9 +141,15 @@ def apply_attn(p, x, cfg, kind, *, positions, taps=None, return_cache=False,
     y = jnp.einsum("bthv,hvd->btd", o, p["wo"])
     cache = None
     if return_cache:
-        cache = {"k": k, "v": v,
+        cache = {"k": fold_heads(k), "v": fold_heads(v),
                  "pos": jnp.full((x.shape[0],), x.shape[1], jnp.int32)}
     return y, cache
+
+
+def fold_heads(x):
+    """(B, T, H, d) -> (B, T, H*d): the cache layout, heads folded into
+    lanes (``init_cache``)."""
+    return x.reshape(x.shape[:2] + (-1,))
 
 
 def _apply_mla(p, x, cfg, *, positions, taps=None, return_cache=False):
@@ -204,7 +210,7 @@ def precompute_cross_cache(p, mem, cfg):
     if "bk" in p:
         k = k + p["bk"].astype(k.dtype)
         v = v + p["bv"].astype(v.dtype)
-    return {"k_mem": k, "v_mem": v}
+    return {"k_mem": fold_heads(k), "v_mem": fold_heads(v)}
 
 
 # ---------------------------------------------------------------------------
@@ -212,7 +218,11 @@ def precompute_cross_cache(p, mem, cfg):
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg, kind: str, batch: int, max_len: int):
-    """Allocate an empty KV cache for one attention layer."""
+    """Allocate an empty KV cache for one attention layer.
+
+    K and V rows are stored ``(B, S, Hkv*d)``: time where the slot, prefix
+    and window code index it, kv heads folded into lanes, which is the
+    layout ``flash_decode`` reads in place."""
     dt = dtype_of(cfg)
     if cfg.mla is not None:
         m = cfg.mla
@@ -224,8 +234,8 @@ def init_cache(cfg, kind: str, batch: int, max_len: int):
     S = min(max_len, cfg.sliding_window) if kind == "swa" else max_len
     dq, dv = cfg.eff_qk, cfg.d_head
     c = {
-        "k": jnp.zeros((batch, S, cfg.n_kv_heads, dq), dt),
-        "v": jnp.zeros((batch, S, cfg.n_kv_heads, dv), dt),
+        "k": jnp.zeros((batch, S, cfg.n_kv_heads * dq), dt),
+        "v": jnp.zeros((batch, S, cfg.n_kv_heads * dv), dt),
         "pos": jnp.zeros((batch,), jnp.int32),
     }
     if kind == "swa":
@@ -233,61 +243,106 @@ def init_cache(cfg, kind: str, batch: int, max_len: int):
     return c
 
 
-def decode_attn(p, x, cache, cfg, kind):
-    """x: (B, 1, D) one new token. Returns (y, new_cache)."""
+IN_PLACE = ("k", "v")      # cache leaves decode_attn reads and writes stacked
+
+
+def reads_in_place(cfg) -> bool:
+    """Whether ``decode_attn`` takes a scanned segment's stacked cache
+    whole (K/V rows written and read at a layer index) — every attention
+    layer but MLA's latent cache."""
+    return cfg.mla is None
+
+
+def layer_of(tree, layer):
+    """Leaves of a stacked cache tree at ``layer`` (a slice)."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        tree)
+
+
+def set_layer(tree, layer, one):
+    """Write one layer's leaves back into the stacked tree at ``layer``."""
+    return jax.tree.map(
+        lambda a, o: jax.lax.dynamic_update_index_in_dim(
+            a, o.astype(a.dtype), layer, 0), tree, one)
+
+
+def decode_attn(p, x, cache, cfg, kind, layer=None):
+    """x: (B, 1, D) one new token. Returns (y, new_cache).
+
+    ``cache`` is one layer's, or with ``layer`` (int32 scalar) the stack
+    of a scanned segment, every leaf with a leading layer axis. K/V rows
+    are then written at ``[layer, b, pos[b]]`` and ``flash_decode`` reads
+    the stack in place; the small leaves (``pos``, ``abs_pos``) are read
+    and written back at ``layer``. One layer's own cache is a stack of one.
+    """
     if cfg.mla is not None and "w_dq" in p:
         return _decode_mla(p, x, cache, cfg)
+    if layer is None:
+        y, stack = decode_attn(p, x, jax.tree.map(lambda a: a[None], cache),
+                               cfg, kind, layer=jnp.int32(0))
+        return y, jax.tree.map(lambda a: a[0], stack)
+    small = layer_of({n: a for n, a in cache.items() if n not in IN_PLACE},
+                     layer)
     B = x.shape[0]
-    pos = cache["pos"]                          # (B,) current length
+    pos = small["pos"]                          # (B,) current length
     positions = pos[:, None]                    # (B, 1)
     q, k_new, v_new = _project_qkv(p, x, cfg, positions, kind, None)
-    S = cache["k"].shape[1]
+    S = cache["k"].shape[2]
     slot = jnp.mod(pos, S) if kind == "swa" else pos
     with jax.named_scope("kv_write"):
-        k = _scatter_time(cache["k"], k_new[:, 0], slot)
-        v = _scatter_time(cache["v"], v_new[:, 0], slot)
+        k = _scatter_time(cache["k"], k_new[:, 0].reshape(B, -1), slot,
+                          layer)
+        v = _scatter_time(cache["v"], v_new[:, 0].reshape(B, -1), slot,
+                          layer)
+    small["pos"] = pos + 1
     if kind == "swa":
-        abs_pos = _scatter_time(cache["abs_pos"][..., None],
-                                pos[:, None], slot)[..., 0]
+        abs_pos = _scatter_time(small["abs_pos"], pos, slot)
         valid = (abs_pos >= 0) & (abs_pos >= (pos[:, None] - S + 1))
+        small["abs_pos"] = abs_pos
     else:
         key_idx = jnp.arange(S)[None, :]
         valid = key_idx <= pos[:, None]
     scale = 1.0 / np.sqrt(cfg.qk_full)
-    y = _decode_sdpa(q, k, v, valid, scale, cfg)
+    y = _decode_sdpa(q, k, v, layer, valid, scale)
     o = jnp.einsum("bhv,hvd->bd", y, p["wo"])[:, None, :]
-    new_cache = dict(cache, k=k, v=v, pos=pos + 1)
-    if kind == "swa":
-        new_cache["abs_pos"] = abs_pos
-    return o, new_cache
+    new_cache = set_layer({n: cache[n] for n in small}, layer, small)
+    return o, dict(new_cache, k=k, v=v)
 
 
-def _scatter_time(buf, val, slot):
-    """buf: (B, S, ...), val: (B, ...), slot: (B,) — write val at [b, slot[b]].
+def _scatter_time(buf, val, slot, layer=None):
+    """buf: (B, S, ...), val: (B, ...), slot: (B,) — write val at [b, slot[b]];
+    with ``layer``, buf is a stack (L, B, S, ...) written at [layer, b, slot[b]].
 
     Indexed scatter (not a one-hot rewrite): XLA updates in place, so the
     decode step never re-materializes the cache (§Perf iteration G1)."""
-    B = buf.shape[0]
-    return buf.at[jnp.arange(B), slot].set(val.astype(buf.dtype))
+    rows = (jnp.arange(val.shape[0]), slot)
+    idx = rows if layer is None else (layer,) + rows
+    return buf.at[idx].set(val.astype(buf.dtype))
 
 
-def _decode_sdpa(q, k, v, valid, scale, cfg):
-    """q: (B,1,H,dq); k/v: (B,S,Hkv,d); valid: (B,S) -> (B,H,dv).
+def _decode_sdpa(q, k, v, layer, valid, scale):
+    """q: (B,1,H,dq); k/v: stacked (L,B,S,Hkv*d) read at ``layer``;
+    valid: (B,S) -> (B,H,dv).
 
     Dispatches to the split-KV flash-decoding Pallas kernel on TPU
-    (repro.kernels.flash_decode); the jnp path contracts the cache in its
-    storage dtype with fp32 accumulation (preferred_element_type) — a
-    wholesale .astype(f32) would materialize an fp32 copy of the entire KV
-    cache per step (§Perf iteration G1).
+    (repro.kernels.flash_decode), which reads the stack in place; the jnp
+    path slices the layer and contracts it in its storage dtype with fp32
+    accumulation (preferred_element_type) — a wholesale .astype(f32) would
+    materialize an fp32 copy of the entire KV cache per step (§Perf
+    iteration G1).
     """
     import os
+    from repro.kernels.flash_decode import ops as fd_ops
     if jax.default_backend() == "tpu" or os.environ.get("REPRO_DECODE_IMPL"):
-        from repro.kernels.flash_decode import ops as fd_ops
-        return fd_ops.decode_attention(q[:, 0], k, v, valid, scale=scale)
+        return fd_ops.decode_attention(q[:, 0], k, v, valid, layer=layer,
+                                       scale=scale)
     from repro.kernels import note_impl
     note_impl("flash_decode", "xla")
+    note_impl("decode_cache", "sliced", n=k.shape[0])
     B, _, H, dq = q.shape
-    Hkv = k.shape[2]
+    Hkv = k.shape[-1] // dq
+    k, v = fd_ops.layer_rows(k, layer, Hkv), fd_ops.layer_rows(v, layer, Hkv)
     g = H // Hkv
     qg = q[:, 0].reshape(B, Hkv, g, dq)
     logits = jnp.einsum("bngq,bsnq->bngs", qg, k,
@@ -335,13 +390,15 @@ def _decode_mla(p, x, cache, cfg):
     return y, dict(cache, ckv=ckv, k_rope=krope, pos=pos + 1)
 
 
-def decode_cross_attn(p, x, cross_cache, cfg):
-    """Decoder cross-attention during decode: memory K/V precomputed."""
+def decode_cross_attn(p, x, cross_cache, cfg, layer=None):
+    """Decoder cross-attention during decode: memory K/V precomputed,
+    ``(B, M, Hkv*d)``, or with ``layer`` a decoder stack read at it."""
     q = jnp.einsum("btd,dhq->bthq", x, p["wq"])
     if "bq" in p:
         q = q + p["bq"].astype(q.dtype)
     k, v = cross_cache["k_mem"], cross_cache["v_mem"]
-    S = k.shape[1]
-    valid = jnp.ones((x.shape[0], S), bool)
-    y = _decode_sdpa(q, k, v, valid, 1.0 / np.sqrt(cfg.qk_full), cfg)
+    if layer is None:
+        k, v, layer = k[None], v[None], jnp.int32(0)
+    valid = jnp.ones((x.shape[0], k.shape[2]), bool)
+    y = _decode_sdpa(q, k, v, layer, valid, 1.0 / np.sqrt(cfg.qk_full))
     return jnp.einsum("bhv,hvd->bd", y, p["wo"])[:, None, :]

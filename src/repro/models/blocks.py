@@ -104,13 +104,24 @@ def init_block_cache(cfg, kind: str, batch: int, max_len: int):
 
 
 def decode_block(p, x, cache, cfg, kind: str, is_moe: bool, *,
-                 cross_cache=None):
-    """One-token decode. x: (B,1,D). Returns (x, new_cache)."""
+                 cross_cache=None, layer=None):
+    """One-token decode. x: (B,1,D). Returns (x, new_cache).
+
+    With ``layer`` (int32 scalar) ``cache`` is a scanned segment's stack,
+    every leaf with a leading layer axis: an attention layer writes and
+    reads its K/V rows in place (``attention.decode_attn``); any other
+    mixer's state is read and written back at ``layer``.
+    """
+    if layer is not None and not (kind in ("attn", "swa")
+                                  and attn_mod.reads_in_place(cfg)):
+        x, one = decode_block(p, x, attn_mod.layer_of(cache, layer), cfg,
+                              kind, is_moe, cross_cache=cross_cache)
+        return x, attn_mod.set_layer(cache, layer, one)
     with jax.named_scope(_mixer_scope(kind)):
         h = apply_norm(p["ln1"], x, cfg)
         if kind in ("attn", "swa"):
             y, new_cache = attn_mod.decode_attn(p["mixer"], h, cache, cfg,
-                                                kind)
+                                                kind, layer=layer)
         elif kind == "mamba":
             y, ms = ssm_mod.apply_mamba(p["mixer"], h, cfg, state=cache)
             new_cache = ms

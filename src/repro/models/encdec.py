@@ -179,21 +179,25 @@ def encdec_decode_step(params, token, cache, cfg):
     pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)  # (B, D)
     x = x + pe[:, None, :].astype(x.dtype)
 
-    def body(carry, slices):
-        x = carry
-        pslice, cslice = slices
+    # the decoder's stacked caches ride in the carry and are read in place
+    # at the layer index (lm.lm_decode_step)
+    def body(carry, xs):
+        x, cstack = carry
+        pslice, layer = xs
         h = apply_norm(pslice["ln1"], x, cfg)
-        y, c_new = attn_mod.decode_attn(pslice["mixer"], h, cslice["self"],
-                                        cfg, "attn")
+        y, c_new = attn_mod.decode_attn(pslice["mixer"], h, cstack["self"],
+                                        cfg, "attn", layer=layer)
         x = x + y
         h = apply_norm(pslice["ln_cross"], x, cfg)
         x = x + attn_mod.decode_cross_attn(pslice["cross"], h,
-                                           cslice["cross"], cfg)
+                                           cstack["cross"], cfg, layer=layer)
         h = apply_norm(pslice["ln2"], x, cfg)
         from repro.models import mlp as mlp_mod
         x = x + mlp_mod.apply_mlp(pslice["mlp"], h, cfg)
-        return x, {"self": c_new, "cross": cslice["cross"]}
+        return (x, {"self": c_new, "cross": cstack["cross"]}), None
 
-    x, new_stack = jax.lax.scan(body, x, (params["dec"]["p0"], cache["dec"]))
+    (x, new_stack), _ = jax.lax.scan(
+        body, (x, cache["dec"]),
+        (params["dec"]["p0"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     x = apply_norm(params["final_norm"], x, cfg)
     return x @ params["head"], {"dec": new_stack, "pos": pos + 1}

@@ -226,18 +226,23 @@ def lm_decode_step(params, token, cache, cfg):
             _, reps, idxs = seg
             specs = [cfg.layer_spec(li) for li in idxs]
 
-            def body(carry, slices):
-                x = carry
-                pslice, cslice = slices
-                ncs = {}
+            # the stacked cache rides in the carry, so each layer writes
+            # its rows in place and the kernel reads the stack where it
+            # lies; passed as scan xs/ys, every layer would be sliced out
+            # and restacked into a fresh buffer
+            def body(carry, xs):
+                x, cseg = carry
+                pslice, layer = xs
+                cseg = dict(cseg)
                 for j, (kind, moe) in enumerate(specs):
-                    x, c = blk.decode_block(pslice[f"p{j}"], x,
-                                            cslice[f"p{j}"], cfg, kind, moe)
-                    ncs[f"p{j}"] = c
-                return x, ncs
+                    x, cseg[f"p{j}"] = blk.decode_block(
+                        pslice[f"p{j}"], x, cseg[f"p{j}"], cfg, kind, moe,
+                        layer=layer)
+                return (x, cseg), None
 
-            x, ncs = jax.lax.scan(body, x, (params[name], cache[name]))
-            new_cache[name] = ncs
+            (x, new_cache[name]), _ = jax.lax.scan(
+                body, (x, cache[name]),
+                (params[name], jnp.arange(reps, dtype=jnp.int32)))
     x = apply_norm(params["final_norm"], x, cfg)
     head = params.get("head")
     if head is None:

@@ -90,8 +90,9 @@ class SlotCache:
     """
 
     def __init__(self, template_fn, n_slots: int, *, sharding=None,
-                 name: str = "slot-cache"):
+                 name: str = "slot-cache", kv_heads=None):
         self.n_slots = n_slots
+        self.kv_heads = kv_heads
         sds1, sds2 = template_fn(1), template_fn(2)
         self.batch_axes = _infer_batch_axes(sds1, sds2)
         self._template = template_fn(n_slots)
@@ -102,7 +103,8 @@ class SlotCache:
             from repro.distrib.sharding import shardings_of
             from repro.serve.sharding import slot_specs
             kw = dict(data_axis=sharding.data_axis,
-                      model_axis=sharding.model_axis, name=name)
+                      model_axis=sharding.model_axis, name=name,
+                      kv_heads=kv_heads)
             self.specs = slot_specs(self._template, self.batch_axes,
                                     sharding.mesh, **kw)
             self.local_specs = slot_specs(sds1, self.batch_axes,
@@ -124,6 +126,7 @@ class SlotCache:
 
     def reset(self):
         """Drop all slot contents (e.g. after compile warmup)."""
+        self.cache = None           # freed first: never two caches at once
         self.cache = self._zeros()
 
     def _write_impl(self, global_c, local_c, slot):
@@ -176,8 +179,9 @@ class RecurrentSlotCache(SlotCache):
     """
 
     def __init__(self, template_fn, n_slots: int, *, sharding=None,
-                 name: str = "slot-cache"):
-        super().__init__(template_fn, n_slots, sharding=sharding, name=name)
+                 name: str = "slot-cache", kv_heads=None):
+        super().__init__(template_fn, n_slots, sharding=sharding, name=name,
+                         kv_heads=kv_heads)
         # batch-1 empty-history state, reused by every reset_slot scatter
         # (placed like a prefill output, so the reset stays shard-local)
         blank = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),

@@ -173,7 +173,8 @@ class ServeEngine:
         cache_cls = RecurrentSlotCache if self.contract == "recurrent" \
             else SlotCache
         self.slotcache = cache_cls(self._cache_template, n_slots,
-                                   sharding=sharding, name=cfg.name)
+                                   sharding=sharding, name=cfg.name,
+                                   kv_heads=cfg.n_kv_heads)
         # sharded: pin out_shardings so the decode/prefill caches keep the
         # slot-cache layout (tokens replicated — every host reads them)
         if sharding is None:

@@ -9,18 +9,21 @@ dim its ``cache_contract`` family parallelises:
 
   contract    leaf                  model-sharded dim
   ---------   -------------------   ------------------------------
-  kv          k / v                 kv heads        (..., S, Hkv, d)
+  kv          k / v                 kv heads        (..., S, Hkv*d)
   kv (MLA)    ckv / k_rope          latent rank     (..., T, r)
   recurrent   wkv                   rwkv heads      (..., H, N, N)
   recurrent   ssm                   ssm channels    (..., d_inner, N)
   recurrent   conv / shift          conv channels   (..., K, d_inner)
-  encdec      k_mem / v_mem         cross heads     (..., M, H, d)
+  encdec      k_mem / v_mem         cross heads     (..., M, Hkv*d)
   (all)       pos / abs_pos         replicated bookkeeping
 
 Dims are counted FROM THE END of the shape, so leading stack axes
 (scanned segments prepend ``(reps, ...)``, enc-dec decoders prepend
-``(n_layers, ...)``) shift nothing. A payload dim that does not divide
-the model-axis size is never padded: the whole config is refused with
+``(n_layers, ...)``) shift nothing. K/V leaves fold their kv heads into
+the last dim (the layout ``flash_decode`` reads in place), so their split
+must fall between heads: ``kv_heads`` must divide by the model-axis size.
+A payload dim that does not divide the model-axis size is never padded:
+the whole config is refused with
 the shared ``shard_ineligible`` message (``serve/errors.py``), which is
 exactly the eligibility matrix ``tests/test_serve_zoo.py`` pins — GQA
 configs whose reduced form collapses to one kv head cannot model-shard.
@@ -39,6 +42,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.serve import errors
 
+# Payload leaves whose model dim folds ``kv_heads`` heads into lanes.
+HEAD_FOLDED_LEAVES = frozenset({"k", "v", "k_mem", "v_mem"})
+
 # Bookkeeping leaves that never shard over the model axis: per-slot valid
 # lengths / ring positions are O(1) per slot and every device's decode
 # mask consumes the whole vector.
@@ -46,8 +52,8 @@ REPLICATED_SLOT_LEAVES = frozenset({"pos", "abs_pos"})
 
 # Payload leaves: the model-axis dim, counted from the end of the shape.
 MODEL_DIM_FROM_END = {
-    "k": 2, "v": 2,            # attn KV rows   (..., S, Hkv, d)
-    "k_mem": 2, "v_mem": 2,    # enc-dec cross  (..., M, H, d)
+    "k": 1, "v": 1,            # attn KV rows   (..., S, Hkv*d)
+    "k_mem": 1, "v_mem": 1,    # enc-dec cross  (..., M, Hkv*d)
     "wkv": 3,                  # rwkv6 state    (..., H, N, N)
     "ssm": 2,                  # mamba state    (..., d_inner, N)
     "conv": 1,                 # mamba conv     (..., K, d_inner)
@@ -91,8 +97,9 @@ def _leaf_name(kp) -> str:
     return str(getattr(kp[-1], "key", getattr(kp[-1], "idx", kp[-1])))
 
 
-def slot_specs(template, batch_axes, mesh, *, data_axis: str = "data",
-               model_axis: str = "model", name: str = "slot-cache"):
+def slot_specs(template, batch_axes, mesh, *, kv_heads=None,
+               data_axis: str = "data", model_axis: str = "model",
+               name: str = "slot-cache"):
     """PartitionSpecs for a slot-cache pytree.
 
     Args:
@@ -106,27 +113,30 @@ def slot_specs(template, batch_axes, mesh, *, data_axis: str = "data",
         comes out data-replicated, which is what the scatter-admit needs).
       mesh: a ``jax.sharding.Mesh`` — or a plain ``{axis: size}`` dict,
         which makes the rules testable without devices.
+      kv_heads: the kv heads folded into the K/V leaves' last dim
+        (``HEAD_FOLDED_LEAVES``); needed to split them over a model axis.
       name: config name for the ``shard_ineligible`` refusal.
 
     Raises:
       ValueError(``errors.msg("shard_ineligible", ...)``) when any payload
-      leaf's model dim does not divide the model-axis size — sharding is
+      leaf's model dim does not divide the model-axis size (for a
+      head-folded leaf: when ``kv_heads`` does not) — sharding is
       all-or-nothing per config, never padded.
 
-    >>> tmpl = {"k": np.zeros((4, 16, 2, 8)), "v": np.zeros((4, 16, 2, 8)),
+    >>> tmpl = {"k": np.zeros((4, 16, 16)), "v": np.zeros((4, 16, 16)),
     ...         "pos": np.zeros((4,), np.int32)}
     >>> axes = {"k": 0, "v": 0, "pos": 0}
-    >>> sp = slot_specs(tmpl, axes, {"data": 2, "model": 2})
-    >>> sp["k"] == P("data", None, "model", None)
+    >>> sp = slot_specs(tmpl, axes, {"data": 2, "model": 2}, kv_heads=2)
+    >>> sp["k"] == P("data", None, "model")
     True
     >>> sp["pos"] == P("data")        # bookkeeping: slot axis only
     True
-    >>> local = slot_specs({"k": np.zeros((1, 16, 2, 8))}, {"k": 0},
-    ...                    {"data": 2, "model": 2})
-    >>> local["k"] == P(None, None, "model", None)   # batch-1: no data dim
+    >>> local = slot_specs({"k": np.zeros((1, 16, 16))}, {"k": 0},
+    ...                    {"data": 2, "model": 2}, kv_heads=2)
+    >>> local["k"] == P(None, None, "model")   # batch-1: no data dim
     True
     >>> try:                          # Hkv=2 cannot split a 4-way axis
-    ...     slot_specs(tmpl, axes, {"model": 4})
+    ...     slot_specs(tmpl, axes, {"model": 4}, kv_heads=2)
     ... except ValueError:
     ...     print("refused")
     refused
@@ -146,7 +156,12 @@ def slot_specs(template, batch_axes, mesh, *, data_axis: str = "data",
             spec[slot_ax] = data_axis
         if m > 1 and leaf_name in MODEL_DIM_FROM_END:
             md = leaf.ndim - MODEL_DIM_FROM_END[leaf_name]
-            if md < 0 or md == slot_ax or leaf.shape[md] % m:
+            folded = leaf_name in HEAD_FOLDED_LEAVES
+            if folded and kv_heads is None:
+                raise TypeError(f"leaf {leaf_name!r} folds kv heads into "
+                                "lanes: slot_specs needs kv_heads=")
+            if md < 0 or md == slot_ax or \
+                    (kv_heads if folded else leaf.shape[md]) % m:
                 raise ValueError(errors.msg("shard_ineligible", name=name,
                                             leaf=leaf_name, m=m))
             spec[md] = model_axis
@@ -162,8 +177,8 @@ def device_bytes_estimate(template, specs, mesh) -> int:
     ``slot_specs`` guarantees). Works on ``jax.eval_shape`` templates, so
     a full-scale (671B-class) config's footprint is computable on a laptop.
 
-    >>> tmpl = {"k": np.zeros((4, 16, 8, 8), np.float32)}
-    >>> sp = {"k": P("data", None, "model", None)}
+    >>> tmpl = {"k": np.zeros((4, 16, 64), np.float32)}
+    >>> sp = {"k": P("data", None, "model")}
     >>> device_bytes_estimate(tmpl, sp, {"data": 2, "model": 4})
     2048
     """

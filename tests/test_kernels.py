@@ -287,6 +287,108 @@ def test_flash_decode_split_invariance(seed, nsplit):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_decode_stacked_in_place_qwen2(dtype):
+    """The stacked cache read in place at a layer other than 0, at qwen2's
+    CORP-pruned head shapes (12 q / 2 kv heads, dq 64, dv 128): lanes at
+    pos 0, mid-block and S-1, and lanes whose later blocks are wholly dead,
+    against the oracle on the one layer cut out."""
+    L, B, S, H, Hkv, dq, dv, bs = 3, 5, 512, 12, 2, 64, 128, 128
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (B, H, dq), dtype)
+    k = jax.random.normal(ks[1], (L, B, S, Hkv * dq), dtype)
+    v = jax.random.normal(ks[2], (L, B, S, Hkv * dv), dtype)
+    pos = jnp.asarray([0, bs // 2, S - 1, bs, 2 * bs + 7])
+    valid = jnp.arange(S)[None, :] <= pos[:, None]
+    layer = 2
+    ref = dref.decode_attention(q, k[layer].reshape(B, S, Hkv, dq),
+                                v[layer].reshape(B, S, Hkv, dv), valid,
+                                scale=0.125)
+    pal = dops.decode_attention(q, k, v, valid, layer=jnp.int32(layer),
+                                scale=0.125, bs=bs, impl="interpret")
+    tol = 3e-2 if dtype == jnp.bfloat16 else 3e-5
+    np.testing.assert_allclose(np.asarray(pal, np.float32),
+                               np.asarray(ref, np.float32), atol=tol,
+                               rtol=tol)
+    # the ref dispatch slices the same layer: same answer, other record
+    from repro import kernels
+    before = kernels.RESOLVED.copy()
+    out = dops.decode_attention(q, k, v, valid, layer=jnp.int32(layer),
+                                scale=0.125, impl="ref")
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=1e-6)
+    took = kernels.RESOLVED - before
+    assert took[("decode_cache", "sliced")] == L, took
+
+
+def test_flash_decode_single_layer_is_a_stack_of_one():
+    """The per-head single-layer layout (B,S,Hkv,d), as cross-attention
+    memory and the kernel tests pass it, equals the same rows stacked
+    (1,B,S,Hkv*d) and read at layer 0, at qwen2's head shapes."""
+    B, S, H, Hkv, dq, dv = 2, 256, 12, 2, 64, 128
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = jax.random.normal(ks[0], (B, H, dq))
+    k = jax.random.normal(ks[1], (B, S, Hkv, dq))
+    v = jax.random.normal(ks[2], (B, S, Hkv, dv))
+    valid = jnp.arange(S)[None, :] <= jnp.asarray([5, S - 1])[:, None]
+    one = dops.decode_attention(q, k, v, valid, bs=64, impl="interpret")
+    stacked = dops.decode_attention(
+        q, k.reshape(1, B, S, -1), v.reshape(1, B, S, -1), valid,
+        layer=jnp.int32(0), bs=64, impl="interpret")
+    ref = dref.decode_attention(q, k, v, valid, scale=1 / np.sqrt(dq))
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(stacked))
+    np.testing.assert_allclose(np.asarray(one), np.asarray(ref), atol=3e-5,
+                               rtol=3e-5)
+
+
+@pytest.mark.parametrize("arch,kw", [
+    ("qwen2-1.5b", {}),                     # one scanned segment
+    ("gemma3-1b", {"n_layers": 8}),         # scan + unrolled tail, swa ring
+    ("jamba-1.5-large-398b", {}),           # mamba state sliced, attn stacked
+    ("seamless-m4t-large-v2", {}),          # decoder stack + cross memory
+])
+def test_decode_step_kernel_matches_jnp_path(arch, kw):
+    """Two decode steps through the kernel (interpret mode), which reads
+    every scanned layer's K/V from the carried stack in place, match the
+    jnp path that slices each layer, in logits and in every cache leaf."""
+    import os
+    from helpers import batch_for, tiny_cfg
+    from repro.models import build_model
+    cfg = tiny_cfg(arch, **kw)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    b = batch_for(cfg, B=2, T=8)
+    batch = {k: b[k] for k in ("tokens", "frames") if k in b}
+    _, cache = model.prefill(params, batch, 16)
+    tok = b["tokens"][:, -1:]
+
+    def two_steps():               # a fresh trace: the impl is read then
+        step = jax.jit(lambda p, t, c: model.decode_step(p, t, c))
+        out, c = [], cache
+        for _ in range(2):
+            lg, c = step(params, tok, c)
+            out.append(lg)
+        return out, c
+
+    from repro import kernels
+    l0, c0 = two_steps()
+    before = kernels.RESOLVED.copy()
+    os.environ["REPRO_DECODE_IMPL"] = "interpret"
+    try:
+        l1, c1 = two_steps()
+    finally:
+        del os.environ["REPRO_DECODE_IMPL"]
+    took = kernels.RESOLVED - before
+    assert took[("flash_decode", "interpret")] and \
+        not took[("decode_cache", "sliced")], took
+    for a, b_ in zip(l0, l1):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-4,
+                                   atol=1e-4)
+    for a, b_ in zip(jax.tree.leaves(c0), jax.tree.leaves(c1)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-4,
+                                   atol=1e-4)
+
+
 def test_decode_path_uses_kernel_consistently():
     """Model decode with REPRO_DECODE_IMPL=interpret must match the jnp
     path bit-for-bit-ish (kernel wired into attention._decode_sdpa)."""

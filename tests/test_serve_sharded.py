@@ -85,7 +85,8 @@ def test_slot_specs_match_contract_per_leaf(arch):
     cfg, eng = _engine(arch)
     assert cache_contract(cfg) == ELIGIBLE[arch]
     sc = eng.slotcache
-    sp = slot_specs(sc._template, sc.batch_axes, MESH2, name=cfg.name)
+    sp = slot_specs(sc._template, sc.batch_axes, MESH2, name=cfg.name,
+                    kv_heads=sc.kv_heads)
     payload = 0
     for (name, leaf), spec, slot_ax in zip(
             _leaf_items(sc._template),
@@ -112,7 +113,8 @@ def test_local_batch1_template_is_data_replicated():
     pinned out_shardings rely on."""
     cfg, eng = _engine("deepseek-7b", n_slots=1)
     one = eng.slotcache
-    local = slot_specs(one._template, one.batch_axes, MESH2, name=cfg.name)
+    local = slot_specs(one._template, one.batch_axes, MESH2, name=cfg.name,
+                       kv_heads=one.kv_heads)
     flat = [tuple(s) for s in jax.tree_util.tree_leaves(
         local, is_leaf=lambda s: isinstance(s, tuple))]
     assert all("data" not in s for s in flat), flat
@@ -123,7 +125,7 @@ def test_model_only_mesh_never_touches_slot_axis():
     cfg, eng = _engine("rwkv6-3b")
     sc = eng.slotcache
     sp = slot_specs(sc._template, sc.batch_axes, {"model": 2},
-                    name=cfg.name)
+                    name=cfg.name, kv_heads=sc.kv_heads)
     flat = [tuple(s) for s in jax.tree_util.tree_leaves(
         sp, is_leaf=lambda s: isinstance(s, tuple))]
     assert all("data" not in s for s in flat), flat
@@ -138,7 +140,8 @@ def test_ineligible_config_refused_never_padded():
     sc = eng.slotcache
     expect = errors.msg("shard_ineligible", name=cfg.name, leaf="k", m=2)
     with pytest.raises(ValueError, match=re.escape(expect)):
-        slot_specs(sc._template, sc.batch_axes, MESH2, name=cfg.name)
+        slot_specs(sc._template, sc.batch_axes, MESH2, name=cfg.name,
+                   kv_heads=sc.kv_heads)
 
 
 def test_device_bytes_estimate_splits_payload_only():
@@ -146,7 +149,8 @@ def test_device_bytes_estimate_splits_payload_only():
     against a perfect 1/N split is the replicated pos-style leaves."""
     cfg, eng = _engine("deepseek-7b")
     sc = eng.slotcache
-    sp = slot_specs(sc._template, sc.batch_axes, MESH2, name=cfg.name)
+    sp = slot_specs(sc._template, sc.batch_axes, MESH2, name=cfg.name,
+                    kv_heads=sc.kv_heads)
     est = device_bytes_estimate(sc._template, sp, MESH2)
     total = eng.cache_bytes
     repl = sum(leaf.size * leaf.dtype.itemsize

@@ -210,7 +210,8 @@ def test_zoo_sharded_admit_two_decodes(zoo, arch):
     model, params = zoo(arch)
     sc = _engine(model, params).slotcache
     slot_specs(sc._template, sc.batch_axes, {"model": SHARD_MESH_M},
-               name=model.cfg.name)       # <- the eligibility decision
+               name=model.cfg.name,
+               kv_heads=sc.kv_heads)      # <- the eligibility decision
     from test_serve_sharded import run_py
     out = run_py(f"""
 import jax, numpy as np

@@ -118,6 +118,151 @@ def test_flash_decode_qwen2(one_chip):
         q, kv, kv, valid)
 
 
+# --- the served decode step at the chat cell's size -------------------------
+
+QWEN2_CORP50 = os.path.join(os.path.dirname(__file__), "..", "bench",
+                            "configs", "qwen2-1.5b-corp50.json")
+SLOTS, MAX_LEN = 64, 4096
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+          "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8}
+# outputs that hold no new buffer, or are the loop itself
+_VIEWS = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+
+
+def _largest_array(shape: str) -> int:
+    """Bytes of the largest array in an HLO shape (an array or a tuple)."""
+    import re
+    best = 0
+    for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]", shape):
+        n = _BYTES.get(dt, 4)
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        best = max(best, n)
+    return best
+
+
+def _instructions(text: str):
+    """(computation, name, shape, opcode, operand names, line) of every
+    instruction of an HLO module's text."""
+    import re
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*)$", line)
+        if not m or comp is None:
+            continue
+        rest = m.group(2)
+        if rest.startswith("("):               # tuple shape
+            depth = 0
+            for i, ch in enumerate(rest):
+                depth += {"(": 1, ")": -1}.get(ch, 0)
+                if depth == 0:
+                    break
+            shape, rest = rest[:i + 1], rest[i + 2:]
+        else:
+            shape, _, rest = rest.partition(" ")
+        op = re.match(r"([\w\-]+)\((.*?)\)", rest)
+        if op:
+            yield (comp, m.group(1), shape, op.group(1),
+                   re.findall(r"%([\w.\-]+)", op.group(2)), line)
+
+
+def cache_sized_buffers(text: str, limit: int) -> list:
+    """Instructions outside fused computations whose output holds ``limit``
+    bytes or more, other than views of the parameters, the loop, and
+    in-place writes whose every other operand is smaller than ``limit``
+    (one row per lane written into the stack). Each is a copy, slice,
+    transpose or allocation of that size."""
+    import re
+    inst = list(_instructions(text))
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    size = {(c, n): _largest_array(sh) for c, n, sh, *_ in inst}
+    writes = {"scatter", "dynamic-update-slice"}
+    writing = {c for c, _, _, op, *_ in inst if op in writes}
+    bad = []
+    for comp, name, shape, op, args, line in inst:
+        if comp in fused or op in _VIEWS or size[(comp, name)] < limit:
+            continue
+        others = sorted(size.get((comp, a), 0) for a in args)[:-1]
+        calls = re.search(r"calls=%([\w.\-]+)", line)
+        write = op in writes or (op == "fusion" and calls
+                                 and calls.group(1) in writing)
+        if write and all(b < limit for b in others):
+            continue
+        bad.append(line.strip()[:200])
+    return bad
+
+
+@pytest.fixture(scope="module")
+def qwen2_decode(topo):
+    """lm_decode_step of qwen2-1.5b-corp50 over 64 slots x 4096 rows, its
+    cache donated as the engine donates it, lowered with the Pallas kernels
+    and compiled for one chip of the described v5e. Returns (config, the
+    compiled program, what the trace recorded in ``kernels.RESOLVED``)."""
+    import json
+    from repro import kernels
+    from repro.configs.base import ModelConfig
+    from repro.models import build_model
+    from repro.models.lm import init_lm_cache
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = ModelConfig(**json.load(open(QWEN2_CORP50))["model"])
+    model = build_model(cfg)
+
+    def placed(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype, one), tree)
+
+    params = placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = placed(jax.eval_shape(
+        lambda: init_lm_cache(cfg, SLOTS, MAX_LEN)))
+    tok = sds((SLOTS, 1), "int32", one)
+
+    def step(params, tok, cache):
+        logits, cache = model.decode_step(params, tok, cache)
+        return jnp.argmax(logits[:, -1, :cfg.vocab_size], -1), cache
+
+    before = kernels.RESOLVED.copy()
+    was = os.environ.get("REPRO_DECODE_IMPL")
+    os.environ["REPRO_DECODE_IMPL"] = "pallas"
+    try:
+        lowered = jax.jit(step, donate_argnums=(2,)).lower(params, tok, cache)
+    finally:
+        if was is None:
+            del os.environ["REPRO_DECODE_IMPL"]
+        else:
+            os.environ["REPRO_DECODE_IMPL"] = was
+    return cfg, lowered.compile(), kernels.RESOLVED - before
+
+
+def test_decode_step_never_copies_the_cache(qwen2_decode):
+    """The served decode step writes one row per lane per layer into the
+    donated stack and ``flash_decode`` reads the stack where it lies: no
+    instruction makes a buffer of one layer's K (64 x 4096 x 128 bf16) or
+    more — no copy, slice, transpose or allocation of the cache — and the
+    program's temporaries stay under one layer's K and V."""
+    cfg, compiled, _ = qwen2_decode
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    k_layer = SLOTS * MAX_LEN * cfg.n_kv_heads * cfg.eff_qk * 2
+    v_layer = SLOTS * MAX_LEN * cfg.n_kv_heads * cfg.d_head * 2
+    assert cache_sized_buffers(text, k_layer) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < k_layer + v_layer, mem
+    # the whole cache is aliased from the donated input to the output
+    assert mem.alias_size_in_bytes >= cfg.n_layers * (k_layer + v_layer)
+
+
+def test_decode_step_reads_every_layer_in_place(qwen2_decode):
+    """The trace records, layer by layer, that the kernel read the stacked
+    cache in place: 28 of 28 for qwen2-1.5b-corp50, none sliced."""
+    cfg, _, took = qwen2_decode
+    assert took[("decode_cache", "in_place")] == cfg.n_layers == 28, took
+    assert took[("decode_cache", "sliced")] == 0, took
+    assert took[("flash_decode", "pallas")] >= 1, took
+
+
 @pytest.mark.parametrize("T", [1, 512])
 def test_wkv6_rwkv6_3b(one_chip, T):
     """rwkv6-3b's recurrence (40 heads of 64): decode and a 512-token
