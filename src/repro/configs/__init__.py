@@ -4,7 +4,8 @@ from __future__ import annotations
 import dataclasses
 
 from repro.configs.base import (MLAConfig, MambaConfig, ModelConfig,
-                                MoEConfig, RWKVConfig, ShapeConfig, SHAPES)
+                                MoEConfig, RopeScaling, RWKVConfig,
+                                ShapeConfig, SHAPES)
 
 _MODULES = {
     "granite-8b": "granite_8b",
@@ -65,13 +66,23 @@ def reduced(cfg: ModelConfig, *, d_model: int = 64, layers_scale: int = 1) -> Mo
         vocab_round=8,
     )
     if cfg.moe is not None:
+        # group-limited routing keeps its shape at toy size: 8 experts in 4
+        # groups, 2 groups a token, top-2
+        grouped = cfg.moe.n_group > 1
         kw["moe"] = dataclasses.replace(
-            cfg.moe, num_experts=4, top_k=2, d_expert=2 * d_model,
-            num_shared=min(cfg.moe.num_shared, 1))
+            cfg.moe, num_experts=8 if grouped else 4, top_k=2,
+            d_expert=2 * d_model, num_shared=min(cfg.moe.num_shared, 1),
+            n_group=4 if grouped else 1, topk_group=2 if grouped else 1,
+            held=None, first_held=0)
         kw["d_ff"] = 2 * d_model
     if cfg.mla is not None:
         kw["mla"] = MLAConfig(q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=16,
                               qk_rope_dim=8, v_dim=16)
+    if cfg.rope_scaling is not None:
+        # YaRN over an original context of 64 positions: at 8 rope dims
+        # every class of frequency (kept, ramped, interpolated) is present
+        kw["rope_scaling"] = dataclasses.replace(
+            cfg.rope_scaling, original_max_position_embeddings=64)
     if cfg.mamba is not None:
         kw["mamba"] = MambaConfig(d_state=4, d_conv=4, expand=2)
     if cfg.rwkv is not None:
@@ -91,6 +102,7 @@ def reduced(cfg: ModelConfig, *, d_model: int = 64, layers_scale: int = 1) -> Mo
 
 
 __all__ = [
-    "ModelConfig", "MoEConfig", "MLAConfig", "MambaConfig", "RWKVConfig",
+    "ModelConfig", "MoEConfig", "MLAConfig", "MambaConfig", "RopeScaling",
+    "RWKVConfig",
     "ShapeConfig", "SHAPES", "ARCH_IDS", "DEIT_IDS", "get_config", "reduced",
 ]

@@ -16,12 +16,24 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts. The router scores every routed expert; a layer
+    holds ``held`` of them, ``first_held`` on (an expert-parallel
+    deployment's share on one chip; default all) and computes only their
+    part of each token's output (``models.mlp.apply_moe``)."""
     num_experts: int
     top_k: int
     d_expert: int                  # per-expert hidden dim
     num_shared: int = 0            # shared (always-on) experts
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training / calibration dispatch only
     router_dtype: str = "float32"
+    scoring: str = "softmax"       # 'softmax' | 'sigmoid' (+ correction
+                                   # bias, DeepSeek-V3's noaux_tc)
+    n_group: int = 1               # expert groups (group-limited routing)
+    topk_group: int = 1            # groups a token may route into
+    routed_scale: float = 1.0      # gate weights times this
+    norm_topk: bool = True         # renormalise the top-k gate weights
+    held: Optional[int] = None     # routed experts held here (None: all)
+    first_held: int = 0            # id of the first held expert
 
 
 @dataclass(frozen=True)
@@ -32,6 +44,19 @@ class MLAConfig:
     qk_nope_dim: int
     qk_rope_dim: int
     v_dim: int
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling as a published ``rope_scaling`` block gives it
+    (``models.common.yarn_inv_freq`` / ``yarn_softmax_scale``)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    type: str = "yarn"
 
 
 @dataclass(frozen=True)
@@ -73,6 +98,7 @@ class ModelConfig:
     sliding_window: int = 1024
     rope_theta: float = 1e4
     rope_theta_local: float = 1e4  # theta for 'swa' layers (gemma3 uses 1e4 local / 1e6 global)
+    rope_scaling: Optional[RopeScaling] = None   # YaRN (MLA rope dims)
     first_k_dense: int = 0         # first k layers use dense FFN even in MoE models
     dense_d_ff: Optional[int] = None  # FFN dim for those dense layers (deepseek-v3: 18432)
     dense_d_ff_kept: Optional[int] = None  # pruned dim for those dense layers
@@ -97,6 +123,18 @@ class ModelConfig:
     dtype: str = "bfloat16"
     vocab_round: int = 128         # embedding table padded to a multiple of this
 
+    def __post_init__(self):
+        """Nested groups given as dicts (a benchmark configuration's JSON)
+        become their dataclasses; a JSON list ``pattern`` a tuple."""
+        groups = {"moe": MoEConfig, "mla": MLAConfig, "mamba": MambaConfig,
+                  "rwkv": RWKVConfig, "rope_scaling": RopeScaling}
+        for name, cls in groups.items():
+            v = getattr(self, name)
+            if isinstance(v, dict):
+                object.__setattr__(self, name, cls(**v))
+        if not isinstance(self.pattern, tuple):
+            object.__setattr__(self, "pattern", tuple(self.pattern))
+
     # -- derived ------------------------------------------------------------
     @property
     def eff_d_ff(self) -> int:
@@ -112,6 +150,12 @@ class ModelConfig:
         assert self.moe is not None
         return self.moe.num_experts if self.experts_kept is None \
             else self.experts_kept
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts this layer holds and computes (``moe.held``)."""
+        held = self.moe.held
+        return self.eff_num_experts if held is None else held
 
     @property
     def eff_dense_d_ff(self) -> Optional[int]:

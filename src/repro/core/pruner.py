@@ -349,6 +349,9 @@ def _fold_moe_experts(p, stats, unit: Unit, pc: PruneConfig, keep, prune,
         new["moe_resid"] = W.reshape(lead + (D, D))
         new["moe_out_b"] = b0.reshape(lead + (D,))
     new["router"] = _gather(p["router"], keep_j, axis=p["router"].ndim - 1)
+    if "router_bias" in p:
+        new["router_bias"] = _gather(p["router_bias"], keep_j,
+                                     axis=p["router_bias"].ndim - 1)
     for k1 in ("wu", "wg", "wd"):
         new[k1] = _gather(p[k1], keep_j, axis=p[k1].ndim - 3)
     if "bd_moe" in p:

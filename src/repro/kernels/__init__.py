@@ -13,7 +13,10 @@ Kernels:
                     calibration statistics hot-spot (Alg. 3/5 inputs).
   wkv6            - RWKV-6 chunked linear-attention recurrence (rwkv6-3b arch).
   flash_decode    - split-KV single-token decode attention (FlashDecoding) —
-                    the memory-bound serving hot path the paper's pruning targets.
+                    the memory-bound serving hot path the paper's pruning
+                    targets; and MLA's absorbed decode over the latent rows.
+  expert_gmm      - grouped matmul of the held experts' (token, expert)
+                    pairs (megablox ``gmm`` on TPU; ops.py + ref.py only).
 """
 import collections
 import contextlib

@@ -22,6 +22,11 @@ Each program serves every head of its lane: q arrives block-diagonal
 ``(H, Hkv*dq)`` (row h holds its query in its kv head's lanes, exact zeros
 elsewhere), so one matmul gives every head's logits against only its own
 kv head; P·V runs once per kv head with the other groups' rows zeroed.
+
+MLA's absorbed decode has its own kernel, ``decode_latent_attention``:
+one latent row ``[ckv | k_rope]`` per token (576 lanes for DeepSeek-V3)
+serves as the key of every head, and its first ``kv_lora_rank`` lanes as
+the value, so each row is read from HBM once for both.
 """
 from __future__ import annotations
 
@@ -117,3 +122,86 @@ def decode_attention_splits(q, k, v, valid, layer, *, scale, bs=512,
     )(jnp.asarray(layer, jnp.int32).reshape(1), qbd, k, v,
       valid.astype(jnp.int32)[:, None, :])
     return acc, m, l
+
+
+# ---------------------------------------------------------------------------
+# the latent shape: MLA's absorbed decode
+# ---------------------------------------------------------------------------
+
+def _latent_kernel(layer_ref, last_ref, q_ref, c_ref, o_ref, m_sc, l_sc,
+                   acc_sc, *, scale, dv, bs):
+    del layer_ref                                  # read by the index maps
+    b, s = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(s == 0)
+    def _init():
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    q = q_ref[...]                                 # (H, C)
+    c = c_ref[...]                                 # (bs, C) = [ckv | k_rope]
+    logits = jax.lax.dot_general(
+        q, c, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale  # (H, bs)
+    row = s * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+    valid = row <= last_ref[b]
+    logits = jnp.where(valid, logits, NEG_INF)
+    m_prev = m_sc[...]
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
+    l_sc[...] = alpha * l_sc[...] + jnp.sum(p, axis=-1, keepdims=True)
+    acc_sc[...] = alpha * acc_sc[...] + jax.lax.dot_general(
+        p.astype(c.dtype), c[:, :dv], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)        # (H, dv)
+    m_sc[...] = m_new
+
+    @pl.when(s == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[...] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "dv", "bs",
+                                             "interpret"))
+def decode_latent_attention(q, lat, last, layer, *, scale, dv, bs=1024,
+                            interpret=False):
+    """q: (B,H,C) absorbed queries; lat: (L,B,S,C) stacked latent rows read
+    in place at ``layer`` (int32 scalar), each row read once as the key
+    and its first ``dv`` lanes as the value; last: (B,) int32, the last
+    valid row of each lane. Returns (B,H,dv) in q's dtype.
+
+    MLA has one latent "head" of C lanes shared by every query head, so a
+    program serves all H heads of one lane against one block of rows; the
+    grid walks the blocks of a lane in order, carrying the online softmax
+    in VMEM, and writes the normalised output once (no split partials).
+    """
+    B, H, C = q.shape
+    S = lat.shape[2]
+    bs = min(bs, S)
+    assert S % bs == 0 and dv <= C, (q.shape, lat.shape, dv)
+    kernel = functools.partial(_latent_kernel, scale=scale, dv=dv, bs=bs)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, S // bs),
+        in_specs=[
+            pl.BlockSpec((None, H, C), lambda b, s, lyr, last: (b, 0, 0)),
+            pl.BlockSpec((None, None, bs, C),
+                         lambda b, s, lyr, last: (lyr[0], b, s, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, H, dv),
+                               lambda b, s, lyr, last: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, dv), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), last.astype(jnp.int32), q,
+      lat)

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 
 from repro.kernels import note_impl, per_device
 from repro.kernels.flash_decode import ref as _ref
-from repro.kernels.flash_decode.flash_decode import decode_attention_splits
+from repro.kernels.flash_decode.flash_decode import (decode_attention_splits,
+                                                     decode_latent_attention)
 
 
 def _block_rows(S: int) -> int:
@@ -89,3 +90,29 @@ def decode_attention(q, k, v, valid, *, layer=None, scale=None, bs=None,
                              jnp.asarray(layer, jnp.int32).reshape(1)),
                       (("batch", "model", None, None), cache, cache,
                        ("batch", None), (None,)), ("batch", "model", None))
+
+
+def decode_latent(q, lat, last, *, layer, scale, dv, bs=None, impl=None):
+    """MLA's absorbed decode attention. q: (B,H,C) = [q_nope·W_uk |
+    q_rope]; lat: the stacked latent cache (L,B,S,C) = [ckv | k_rope],
+    read at ``layer``; last: (B,) the last valid row -> (B,H,dv), the
+    attention-weighted sum of each row's first ``dv`` lanes."""
+    S = lat.shape[2]
+    bs = bs or _block_rows(S)
+    impl = note_impl("flash_decode", impl or _resolve_impl(S, bs))
+    note_impl("decode_cache", "sliced" if impl == "ref" else "in_place",
+              n=lat.shape[0])
+    if impl == "ref":
+        one = jax.lax.dynamic_index_in_dim(lat, layer, 0, keepdims=False)
+        return _ref.decode_latent(q, one, last, scale, dv)
+
+    def kern(q, lat, last, layer):
+        return decode_latent_attention(q, lat, last, layer[0], scale=scale,
+                                       dv=dv, bs=min(bs, S),
+                                       interpret=(impl == "interpret"))
+
+    # every head reads the whole latent row: lanes never split
+    return per_device(kern, (q, lat, last,
+                             jnp.asarray(layer, jnp.int32).reshape(1)),
+                      (("batch", None, None), (None, "batch", None, None),
+                       ("batch",), (None,)), ("batch", None, None))

@@ -7,6 +7,8 @@
   prefill(params, batch, max_len[, lengths]) -> (logits, cache)
                                         (lengths: ragged right-padded prompts)
   decode_step(params, token, cache)  -> (logits, cache)
+                                        (lm: ``count_lanes`` adds the
+                                        MoE counters, ``lm_decode_step``)
   init_cache(batch, max_len)         -> empty cache (decode-only dry-runs)
 
 ``input_specs(cfg, shape)`` returns jax.ShapeDtypeStruct stand-ins for every
@@ -62,8 +64,8 @@ def build_model(cfg: ModelConfig) -> Model:
             loss=lambda params, batch, train=True:
                 lm_mod.lm_loss(params, batch, cfg, train=train),
             prefill=prefill_fn,
-            decode_step=lambda params, token, cache:
-                lm_mod.lm_decode_step(params, token, cache, cfg),
+            decode_step=lambda params, token, cache, **kw:
+                lm_mod.lm_decode_step(params, token, cache, cfg, **kw),
             init_cache=lambda batch, max_len:
                 lm_mod.init_lm_cache(cfg, batch, max_len),
         )

@@ -23,7 +23,8 @@ import numpy as np
 from repro.distrib.sharding import constrain, constrain_qkv
 from repro.kernels.flash_attention import ops as flash_ops
 from repro.models.common import (apply_rope, dense_init, dtype_of,
-                                 rms_head_norm, rope_freqs, tap)
+                                 rms_head_norm, rope_freqs, tap, yarn_gains,
+                                 yarn_inv_freq)
 
 
 # ---------------------------------------------------------------------------
@@ -70,7 +71,6 @@ def _init_mla(key, cfg):
     D, H = cfg.d_model, cfg.n_heads
     nope = cfg.eff_qk           # prunable block
     ks = jax.random.split(key, 8)
-    inv = jnp.asarray(rope_freqs(m.qk_rope_dim, cfg.rope_theta), jnp.float32)
     return {
         "w_dq": dense_init(ks[0], (D, m.q_lora_rank), dt),
         "q_norm_scale": jnp.ones((m.q_lora_rank,), jnp.float32),
@@ -83,8 +83,42 @@ def _init_mla(key, cfg):
         "w_uv": dense_init(ks[6], (m.kv_lora_rank, H, m.v_dim), dt),
         "wo": dense_init(ks[7], (H, m.v_dim, D), dt,
                          scale=1.0 / np.sqrt(H * m.v_dim)),
-        "rope_inv": inv,
     }
+
+
+def mla_rope(cfg):
+    """(inverse frequencies, cos/sin gain) of MLA's decoupled rope dims:
+    plain rope at ``rope_theta``, or YaRN under ``cfg.rope_scaling``."""
+    r, rs = cfg.mla.qk_rope_dim, cfg.rope_scaling
+    if rs is None:
+        return rope_freqs(r, cfg.rope_theta), 1.0
+    return yarn_inv_freq(r, cfg.rope_theta, rs), yarn_gains(rs)[0]
+
+
+def latent_lanes(cfg) -> int:
+    """Lanes of one latent cache row: [ckv | k_rope] zero-padded to whole
+    128-lane tiles (576 -> 640 for DeepSeek-V3, which a TPU tile pads to in
+    memory anyway). An unaligned row lets the compiler lay the cache out
+    time-minor and copy it whole on every step."""
+    m = cfg.mla
+    return -(-(m.kv_lora_rank + m.qk_rope_dim) // 128) * 128
+
+
+def _latent_row(ckv, k_rope, lanes):
+    """(..., R), (..., r) -> the cache row (..., lanes)."""
+    pad = lanes - ckv.shape[-1] - k_rope.shape[-1]
+    zeros = jnp.zeros(ckv.shape[:-1] + (pad,), ckv.dtype)
+    return jnp.concatenate([ckv, k_rope.astype(ckv.dtype), zeros], -1)
+
+
+def mla_scale(cfg) -> float:
+    """Softmax scale of MLA: 1/sqrt(nope + rope) of the published widths
+    (CORP folds its compensation into the weights and keeps the scale),
+    times YaRN's mscale^2."""
+    m = cfg.mla
+    soft = 1.0 if cfg.rope_scaling is None else yarn_gains(
+        cfg.rope_scaling)[1]
+    return float(soft / np.sqrt(m.qk_nope_dim + m.qk_rope_dim))
 
 
 # ---------------------------------------------------------------------------
@@ -165,20 +199,23 @@ def _apply_mla(p, x, cfg, *, positions, taps=None, return_cache=False):
     k_nope = jnp.einsum("btr,rhq->bthq", ckv_n, p["w_uk_nope"])
     v = jnp.einsum("btr,rhv->bthv", ckv_n, p["w_uv"])
     # rope on the decoupled block (shared key, per-head query)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    k_rope1 = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)
+    inv, gain = mla_rope(cfg)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, inv, gain)
+    k_rope1 = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                         inv, gain)
     tap(taps, "q", q_nope)
     tap(taps, "k", k_nope)
     k_rope_h = jnp.broadcast_to(k_rope1, (B, T, cfg.n_heads, m.qk_rope_dim))
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
     k_full = jnp.concatenate([k_nope, k_rope_h], axis=-1)
     q_full, k_full, v = constrain_qkv(q_full, k_full, v)
-    scale = 1.0 / np.sqrt(m.qk_nope_dim + m.qk_rope_dim)
-    o = flash_ops.attention(q_full, k_full, v, causal=True, scale=scale)
+    o = flash_ops.attention(q_full, k_full, v, causal=True,
+                            scale=mla_scale(cfg))
     y = jnp.einsum("bthv,hvd->btd", o, p["wo"])
     cache = None
     if return_cache:
-        cache = {"ckv": ckv_n, "k_rope": k_rope1[:, :, 0, :],
+        cache = {"lat": _latent_row(ckv_n, k_rope1[:, :, 0, :],
+                                    latent_lanes(cfg)),
                  "pos": jnp.full((B,), T, jnp.int32)}
     return y, cache
 
@@ -225,10 +262,10 @@ def init_cache(cfg, kind: str, batch: int, max_len: int):
     layout ``flash_decode`` reads in place."""
     dt = dtype_of(cfg)
     if cfg.mla is not None:
-        m = cfg.mla
+        # one latent row per token: [normed ckv | roped k | zeros], read
+        # whole as the key and its first kv_lora_rank lanes as the value
         return {
-            "ckv": jnp.zeros((batch, max_len, m.kv_lora_rank), dt),
-            "k_rope": jnp.zeros((batch, max_len, m.qk_rope_dim), dt),
+            "lat": jnp.zeros((batch, max_len, latent_lanes(cfg)), dt),
             "pos": jnp.zeros((batch,), jnp.int32),
         }
     S = min(max_len, cfg.sliding_window) if kind == "swa" else max_len
@@ -243,14 +280,8 @@ def init_cache(cfg, kind: str, batch: int, max_len: int):
     return c
 
 
-IN_PLACE = ("k", "v")      # cache leaves decode_attn reads and writes stacked
-
-
-def reads_in_place(cfg) -> bool:
-    """Whether ``decode_attn`` takes a scanned segment's stacked cache
-    whole (K/V rows written and read at a layer index) — every attention
-    layer but MLA's latent cache."""
-    return cfg.mla is None
+# cache leaves decode_attn reads and writes stacked
+IN_PLACE = ("k", "v", "lat")
 
 
 def layer_of(tree, layer):
@@ -275,13 +306,14 @@ def decode_attn(p, x, cache, cfg, kind, layer=None):
     are then written at ``[layer, b, pos[b]]`` and ``flash_decode`` reads
     the stack in place; the small leaves (``pos``, ``abs_pos``) are read
     and written back at ``layer``. One layer's own cache is a stack of one.
+    MLA's latent rows are written and read the same way.
     """
-    if cfg.mla is not None and "w_dq" in p:
-        return _decode_mla(p, x, cache, cfg)
     if layer is None:
         y, stack = decode_attn(p, x, jax.tree.map(lambda a: a[None], cache),
                                cfg, kind, layer=jnp.int32(0))
         return y, jax.tree.map(lambda a: a[0], stack)
+    if cfg.mla is not None and "w_dq" in p:
+        return _decode_mla(p, x, cache, cfg, layer)
     small = layer_of({n: a for n, a in cache.items() if n not in IN_PLACE},
                      layer)
     B = x.shape[0]
@@ -354,40 +386,41 @@ def _decode_sdpa(q, k, v, layer, valid, scale):
     return o.reshape(B, H, -1).astype(q.dtype)
 
 
-def _decode_mla(p, x, cache, cfg):
-    """MLA decode with the absorbed-matmul trick (latent-space cache)."""
+def _decode_mla(p, x, cache, cfg, layer):
+    """MLA decode, absorbed: q_nope·W_uk turns each head's query into the
+    latent space, so attention runs over the stacked latent rows
+    ``(L, B, S, latent_lanes)`` where they lie (``flash_decode``'s latent
+    kernel), and W_uv lifts the latent output to the heads."""
     m = cfg.mla
-    B = x.shape[0]
-    pos = cache["pos"]
-    positions = pos[:, None]
-    cq = jnp.einsum("btd,dr->btr", x, p["w_dq"])
-    cq = rms_head_norm(cq, p["q_norm_scale"], cfg.norm_eps)
-    q_nope = jnp.einsum("btr,rhq->bthq", cq, p["w_uq_nope"])[:, 0]
-    q_rope = jnp.einsum("btr,rhq->bthq", cq, p["w_uq_rope"])
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)[:, 0]
-    ckv_new = jnp.einsum("btd,dr->btr", x, p["w_dkv"])
-    ckv_new = rms_head_norm(ckv_new, p["kv_norm_scale"], cfg.norm_eps)
-    kr_new = jnp.einsum("btd,dq->btq", x, p["w_k_rope"])
-    kr_new = apply_rope(kr_new[:, :, None, :], positions,
-                        cfg.rope_theta)[:, 0, 0]
-    ckv = _scatter_time(cache["ckv"], ckv_new[:, 0], pos)
-    krope = _scatter_time(cache["k_rope"], kr_new, pos)
-    # absorb W_uk into q: q_eff (B,H,r)
-    q_eff = jnp.einsum("bhq,rhq->bhr", q_nope, p["w_uk_nope"])
-    S = ckv.shape[1]
-    lo_n = jnp.einsum("bhr,bsr->bhs", q_eff.astype(jnp.float32),
-                      ckv.astype(jnp.float32))
-    lo_r = jnp.einsum("bhq,bsq->bhs", q_rope.astype(jnp.float32),
-                      krope.astype(jnp.float32))
-    scale = 1.0 / np.sqrt(m.qk_nope_dim + m.qk_rope_dim)
-    logits = (lo_n + lo_r) * scale
-    valid = jnp.arange(S)[None, :] <= pos[:, None]
-    logits = jnp.where(valid[:, None, :], logits, -1e30)
-    w = jax.nn.softmax(logits, axis=-1)
-    o_lat = jnp.einsum("bhs,bsr->bhr", w, ckv.astype(jnp.float32))
-    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(x.dtype), p["w_uv"])
-    y = jnp.einsum("bhv,hvd->bd", o, p["wo"])[:, None, :]
-    return y, dict(cache, ckv=ckv, k_rope=krope, pos=pos + 1)
+    R = m.kv_lora_rank
+    with jax.named_scope("mla"):
+        pos = layer_of({"pos": cache["pos"]}, layer)["pos"]  # (B,)
+        positions = pos[:, None]
+        inv, gain = mla_rope(cfg)
+        cq = jnp.einsum("btd,dr->btr", x, p["w_dq"])
+        cq = rms_head_norm(cq, p["q_norm_scale"], cfg.norm_eps)
+        q_nope = jnp.einsum("btr,rhq->bthq", cq, p["w_uq_nope"])[:, 0]
+        q_rope = jnp.einsum("btr,rhq->bthq", cq, p["w_uq_rope"])
+        q_rope = apply_rope(q_rope, positions, cfg.rope_theta, inv,
+                            gain)[:, 0]
+        ckv_new = jnp.einsum("btd,dr->btr", x, p["w_dkv"])
+        ckv_new = rms_head_norm(ckv_new, p["kv_norm_scale"], cfg.norm_eps)
+        kr_new = jnp.einsum("btd,dq->btq", x, p["w_k_rope"])
+        kr_new = apply_rope(kr_new[:, :, None, :], positions, cfg.rope_theta,
+                            inv, gain)[:, 0, 0]
+        lanes = cache["lat"].shape[-1]
+        with jax.named_scope("kv_write"):
+            lat = _scatter_time(cache["lat"], _latent_row(
+                ckv_new[:, 0], kr_new, lanes), pos, layer)
+        q_eff = jnp.einsum("bhq,rhq->bhr", q_nope, p["w_uk_nope"])
+        q_cat = _latent_row(q_eff, q_rope, lanes)   # zeros meet the pad
+        from repro.kernels.flash_decode import ops as fd_ops
+        o_lat = fd_ops.decode_latent(q_cat.astype(lat.dtype), lat, pos,
+                                     layer=layer, scale=mla_scale(cfg), dv=R)
+        o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(x.dtype), p["w_uv"])
+        y = jnp.einsum("bhv,hvd->bd", o, p["wo"])[:, None, :]
+    new = set_layer({"pos": cache["pos"]}, layer, {"pos": pos + 1})
+    return y, dict(new, lat=lat)
 
 
 def decode_cross_attn(p, x, cross_cache, cfg, layer=None):

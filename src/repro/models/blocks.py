@@ -104,18 +104,20 @@ def init_block_cache(cfg, kind: str, batch: int, max_len: int):
 
 
 def decode_block(p, x, cache, cfg, kind: str, is_moe: bool, *,
-                 cross_cache=None, layer=None):
+                 cross_cache=None, layer=None, counts=None):
     """One-token decode. x: (B,1,D). Returns (x, new_cache).
 
     With ``layer`` (int32 scalar) ``cache`` is a scanned segment's stack,
     every leaf with a leading layer axis: an attention layer writes and
-    reads its K/V rows in place (``attention.decode_attn``); any other
-    mixer's state is read and written back at ``layer``.
+    reads its K/V (or MLA latent) rows in place
+    (``attention.decode_attn``); any other mixer's state is read and
+    written back at ``layer``. ``counts`` (a dict) collects an MoE
+    layer's counters (``mlp.apply_moe``).
     """
-    if layer is not None and not (kind in ("attn", "swa")
-                                  and attn_mod.reads_in_place(cfg)):
+    if layer is not None and kind not in ("attn", "swa"):
         x, one = decode_block(p, x, attn_mod.layer_of(cache, layer), cfg,
-                              kind, is_moe, cross_cache=cross_cache)
+                              kind, is_moe, cross_cache=cross_cache,
+                              counts=counts)
         return x, attn_mod.set_layer(cache, layer, one)
     with jax.named_scope(_mixer_scope(kind)):
         h = apply_norm(p["ln1"], x, cfg)
@@ -140,7 +142,7 @@ def decode_block(p, x, cache, cfg, kind: str, is_moe: bool, *,
                                                state=cache["channel"])
             new_cache = dict(new_cache, channel=cs)
         elif is_moe:
-            y, _ = mlp_mod.apply_moe(p["mlp"], h, cfg)
+            y, _ = mlp_mod.apply_moe(p["mlp"], h, cfg, counts=counts)
         else:
             y = mlp_mod.apply_mlp(p["mlp"], h, cfg)
         return x + y, new_cache

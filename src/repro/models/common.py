@@ -1,6 +1,8 @@
 """Shared model components: norms, rope, activations, initializers, taps."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -87,19 +89,58 @@ def rope_freqs(dim: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., T, H, D) with D even, positions: (..., T) int32."""
+def apply_rope(x, positions, theta: float, inv=None, gain: float = 1.0):
+    """x: (..., T, H, D) with D even, positions: (..., T) int32. Pairs
+    (2i, 2i+1) turn at ``inv[i]`` (default ``rope_freqs(D, theta)``); the
+    result is scaled by ``gain`` (YaRN's cos/sin factor)."""
     d = x.shape[-1]
-    inv = jnp.asarray(rope_freqs(d, theta), jnp.float32)
+    if inv is None:
+        inv = rope_freqs(d, theta)
+    inv = jnp.asarray(inv, jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * inv  # (..., T, D/2)
-    cos = jnp.cos(ang)[..., None, :]
-    sin = jnp.sin(ang)[..., None, :]
+    cos = jnp.cos(ang)[..., None, :] * gain
+    sin = jnp.sin(ang)[..., None, :] * gain
     x1 = x[..., 0::2].astype(jnp.float32)
     x2 = x[..., 1::2].astype(jnp.float32)
     o1 = x1 * cos - x2 * sin
     o2 = x2 * cos + x1 * sin
     out = jnp.stack([o1, o2], axis=-1).reshape(x.shape)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor for a context ``factor`` times longer."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, rs) -> np.ndarray:
+    """YaRN's per-pair inverse frequencies (DeepSeek-V3's
+    ``DeepseekV3YarnRotaryEmbedding``): pairs that turn fewer than
+    ``beta_slow`` times over the original context are interpolated
+    (frequency / ``factor``), those that turn more than ``beta_fast``
+    times are kept, and a linear ramp blends the pairs between."""
+    def corr_dim(rot):
+        return dim * math.log(rs.original_max_position_embeddings
+                              / (rot * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(corr_dim(rs.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(rs.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    extra = rope_freqs(dim, theta)
+    kept = 1.0 - ramp
+    return extra / rs.factor * (1.0 - kept) + extra * kept
+
+
+def yarn_gains(rs) -> tuple:
+    """(rope gain on cos/sin, factor on the softmax scale) of a YaRN
+    block: mscale(factor, mscale) / mscale(factor, mscale_all_dim), and
+    mscale(factor, mscale_all_dim)^2 where ``mscale_all_dim`` is set."""
+    f = rs.factor
+    gain = yarn_mscale(f, rs.mscale) / yarn_mscale(f, rs.mscale_all_dim)
+    soft = yarn_mscale(f, rs.mscale_all_dim) ** 2 if rs.mscale_all_dim \
+        else 1.0
+    return gain, soft
 
 
 # ---------------------------------------------------------------------------

@@ -208,19 +208,32 @@ def init_lm_cache(cfg, batch: int, max_len: int):
     return caches
 
 
-def lm_decode_step(params, token, cache, cfg):
-    """token: (B, 1) int32. Returns (logits (B,1,V), new_cache)."""
+def lm_decode_step(params, token, cache, cfg, count_lanes=None):
+    """token: (B, 1) int32. Returns (logits (B,1,V), new_cache), and with
+    ``count_lanes`` ((B,) bool: the lanes that hold a request) the MoE
+    counters of those lanes summed over the layers, int32 (2,):
+    (token, held-expert) pairs and held experts that got one."""
     x = params["embed"][token]
     new_cache = {"pos": cache["pos"] + 1}
+    total = jnp.zeros((2,), jnp.int32)
+
+    def lane_counts():
+        return None if count_lanes is None else {"lanes": count_lanes}
+
+    def moe_counts(c):
+        return (c or {}).get("moe", jnp.zeros((2,), jnp.int32))
+
     for si, seg in enumerate(cfg.layout()):
         name = _seg_name(si)
         if seg[0] == "unroll":
             nc = {}
             for j, li in enumerate(seg[1]):
                 kind, moe = cfg.layer_spec(li)
-                x, c = blk.decode_block(params[name][f"l{j}"], x,
-                                        cache[name][f"l{j}"], cfg, kind, moe)
-                nc[f"l{j}"] = c
+                c = lane_counts()
+                x, nc[f"l{j}"] = blk.decode_block(
+                    params[name][f"l{j}"], x, cache[name][f"l{j}"], cfg,
+                    kind, moe, counts=c)
+                total = total + moe_counts(c)
             new_cache[name] = nc
         else:
             _, reps, idxs = seg
@@ -231,22 +244,26 @@ def lm_decode_step(params, token, cache, cfg):
             # lies; passed as scan xs/ys, every layer would be sliced out
             # and restacked into a fresh buffer
             def body(carry, xs):
-                x, cseg = carry
+                x, cseg, total = carry
                 pslice, layer = xs
                 cseg = dict(cseg)
                 for j, (kind, moe) in enumerate(specs):
+                    c = lane_counts()
                     x, cseg[f"p{j}"] = blk.decode_block(
                         pslice[f"p{j}"], x, cseg[f"p{j}"], cfg, kind, moe,
-                        layer=layer)
-                return (x, cseg), None
+                        layer=layer, counts=c)
+                    total = total + moe_counts(c)
+                return (x, cseg, total), None
 
-            (x, new_cache[name]), _ = jax.lax.scan(
-                body, (x, cache[name]),
+            (x, new_cache[name], total), _ = jax.lax.scan(
+                body, (x, cache[name], total),
                 (params[name], jnp.arange(reps, dtype=jnp.int32)))
     x = apply_norm(params["final_norm"], x, cfg)
     head = params.get("head")
     if head is None:
         head = params["embed"].T
+    if count_lanes is not None:
+        return x @ head, new_cache, total
     return x @ head, new_cache
 
 
@@ -362,7 +379,7 @@ def override_cache_pos(tree, lengths):
 def _pad_cache(c, max_len):
     """Right-pad a freshly built cache to max_len time slots."""
     out = dict(c)
-    for key in ("k", "v", "ckv", "k_rope"):
+    for key in ("k", "v", "lat"):
         if key in c:
             T = c[key].shape[1]
             if T < max_len:

@@ -191,6 +191,10 @@ class ServeEngine:
         # entry whose buffers must survive the call.
         self._decode1 = jax.jit(self._decode_impl, out_shardings=local_out)
         self._prefill = jax.jit(self._prefill_impl, out_shardings=local_out)
+        # an MoE decoder's step hands back the expert counters of the
+        # lanes that hold a request behind the tokens, in the one fetch
+        # the step makes
+        self.moe_counts = cfg.moe is not None and cfg.family == "lm"
         self.stats = collections.Counter()
         self._t0 = None
 
@@ -225,8 +229,12 @@ class ServeEngine:
                 lengths=lengths if self.ragged_ok else None)
         return self._argmax(logits), cache
 
-    def _decode_impl(self, params, tok, cache):
+    def _decode_impl(self, params, tok, cache, lanes=None):
         with self._kernel_mesh():
+            if lanes is not None:
+                logits, cache, counts = self.model.decode_step(
+                    params, tok, cache, count_lanes=lanes)
+                return jnp.concatenate([self._argmax(logits), counts]), cache
             logits, cache = self.model.decode_step(params, tok, cache)
         return self._argmax(logits), cache
 
@@ -450,15 +458,24 @@ class ServeEngine:
 
     def decode_step(self):
         """One shared decode step over every slot; returns retired slots."""
-        active = self.decoding_count()
+        live = np.fromiter((not s.free and s.pending is None
+                            for s in self.slots), bool, len(self.slots))
+        active = int(live.sum())
         with span("engine.decode", step=self.stats["decode_steps"],
                   lanes=active):
+            # handed over as numpy: jnp.asarray of a list of bools costs
+            # ~1 ms of host time a step
+            counted = (live,) if self.moe_counts else ()
             nxt, cache = self._decode(self.params,
                                       jnp.asarray(self.tokens[:, None]),
-                                      self.slotcache.cache)
+                                      self.slotcache.cache, *counted)
             self.slotcache.cache = cache
             with span("engine.decode.wait"):
                 nxt = np.asarray(nxt)
+            if self.moe_counts:
+                # summed over the step's MoE layers
+                self.stats["moe_pairs"] += int(nxt[self.n_slots])
+                self.stats["moe_experts_hit"] += int(nxt[self.n_slots + 1])
             self.stats["decode_steps"] += 1
             self.stats["decode_lanes"] += active
             self.stats["max_concurrent"] = max(self.stats["max_concurrent"],

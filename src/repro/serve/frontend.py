@@ -225,7 +225,10 @@ class ServeFrontend:
             retired = self.engine.decode_step()
             with span("frontend.stream", lanes=len(self._by_slot)):
                 for slot, h in self._by_slot.items():
-                    h.tokens = [int(t) for t in self.engine.slots[slot].out]
+                    # a slot's tokens only grow while it serves h: take
+                    # the new ones (a full copy per step costs O(length))
+                    out = self.engine.slots[slot].out
+                    h.tokens.extend(int(t) for t in out[len(h.tokens):])
                 for slot in retired:
                     h = self._by_slot.pop(slot)
                     comp = self.engine.retire(slot)

@@ -10,7 +10,7 @@ dim its ``cache_contract`` family parallelises:
   contract    leaf                  model-sharded dim
   ---------   -------------------   ------------------------------
   kv          k / v                 kv heads        (..., S, Hkv*d)
-  kv (MLA)    ckv / k_rope          latent rank     (..., T, r)
+  kv (MLA)    lat                   latent lanes    (..., T, r + rope)
   recurrent   wkv                   rwkv heads      (..., H, N, N)
   recurrent   ssm                   ssm channels    (..., d_inner, N)
   recurrent   conv / shift          conv channels   (..., K, d_inner)
@@ -58,8 +58,7 @@ MODEL_DIM_FROM_END = {
     "ssm": 2,                  # mamba state    (..., d_inner, N)
     "conv": 1,                 # mamba conv     (..., K, d_inner)
     "shift": 1,                # rwkv shifts    (..., D)
-    "ckv": 1,                  # MLA latent     (..., T, rank)
-    "k_rope": 1,               # MLA rope keys  (..., T, r_rope)
+    "lat": 1,                  # MLA latent     (..., T, rank + rope)
 }
 
 

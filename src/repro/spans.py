@@ -49,10 +49,16 @@ NAMES = {
     "jit__prefill_impl": "program",   # ServeEngine._prefill_impl
     "jit_forward": "program",         # the classify benchmark's forward
     "decode_attention_splits": "kernel",   # kernels/flash_decode
+    "decode_latent_attention": "kernel",   # kernels/flash_decode, MLA
+    "gmm": "kernel",                  # kernels/expert_gmm (megablox)
     "flash_attention": "kernel",      # kernels/flash_attention
     "attn": "scope",                  # blocks: the attention half
     "mlp": "scope",                   # blocks: the MLP half
     "kv_write": "scope",              # attention.decode_attn: cache writes
+    "mla": "scope",                   # attention: MLA's latent decode
+                                      # (absorb, kernel, up-projection)
+    "moe.route": "scope",             # mlp.route: the router
+    "moe.experts": "scope",           # mlp: the held experts' pairs
 }
 
 

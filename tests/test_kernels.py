@@ -346,11 +346,14 @@ def test_flash_decode_single_layer_is_a_stack_of_one():
     ("gemma3-1b", {"n_layers": 8}),         # scan + unrolled tail, swa ring
     ("jamba-1.5-large-398b", {}),           # mamba state sliced, attn stacked
     ("seamless-m4t-large-v2", {}),          # decoder stack + cross memory
+    ("deepseek-v3-671b", {}),               # MLA latent stack, unrolled
+                                            # dense layer + scanned MoE
 ])
 def test_decode_step_kernel_matches_jnp_path(arch, kw):
     """Two decode steps through the kernel (interpret mode), which reads
-    every scanned layer's K/V from the carried stack in place, match the
-    jnp path that slices each layer, in logits and in every cache leaf."""
+    every scanned layer's K/V (or MLA latent rows) from the carried stack
+    in place, match the jnp path that slices each layer, in logits and in
+    every cache leaf."""
     import os
     from helpers import batch_for, tiny_cfg
     from repro.models import build_model

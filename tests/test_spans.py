@@ -135,7 +135,7 @@ def test_program_and_kernel_names_match_the_benchmark():
         for path in glob.glob(os.path.join(ROOT, "bench", sub, "*.py")):
             with open(path) as f:
                 text += f.read()
-    kernels = set(re.findall(r'"flash_\w+": "(\w+)"', text))
+    kernels = set(re.findall(r'"(?:flash|expert)_\w+": "(\w+)"', text))
     programs = {"jit_" + fn for fn in
                 re.findall(r'program_time\([^,]+, "(\w+)"\)', text)}
     assert kernels == set(spans.of_kind("kernel"))

@@ -289,3 +289,85 @@ def test_kernels_per_device_on_four_chips(mesh):
             return (dops.decode_attention(q, k, v, m, impl="pallas"),
                     fops.attention(s, s, s, causal=True, impl="pallas"))
     assert "tpu_custom_call" in compiled_text(step, q, kv, kv, valid, seq)
+
+
+# --- the served decode step at the reasoning cell's size ---------------------
+
+DEEPSEEK_V3_CORP50 = os.path.join(os.path.dirname(__file__), "..", "bench",
+                                  "configs", "deepseek-v3-corp50.json")
+MOE_SLOTS = 256
+
+
+@pytest.fixture(scope="module")
+def deepseek_decode(topo):
+    """lm_decode_step of deepseek-v3-corp50 (1 dense + 4 MoE layers, 8 of
+    256 experts held) over 256 slots x 4096 latent rows, its cache donated
+    as the engine donates it, lowered with the Pallas kernels and compiled
+    for one chip of the described v5e. Returns (config, the compiled
+    program, what the trace recorded in ``kernels.RESOLVED``)."""
+    import json
+    from repro import kernels
+    from repro.configs.base import ModelConfig
+    from repro.models import build_model
+    from repro.models.lm import init_lm_cache
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = ModelConfig(**json.load(open(DEEPSEEK_V3_CORP50))["model"])
+    model = build_model(cfg)
+
+    def placed(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype, one), tree)
+
+    params = placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = placed(jax.eval_shape(
+        lambda: init_lm_cache(cfg, MOE_SLOTS, MAX_LEN)))
+    tok = sds((MOE_SLOTS, 1), "int32", one)
+    lanes = sds((MOE_SLOTS,), "bool", one)
+
+    def step(params, tok, cache, lanes):
+        logits, cache, counts = model.decode_step(params, tok, cache,
+                                                  count_lanes=lanes)
+        return jnp.argmax(logits[:, -1, :cfg.vocab_size], -1), counts, cache
+
+    before = kernels.RESOLVED.copy()
+    env = {"REPRO_DECODE_IMPL": "pallas", "REPRO_GMM_IMPL": "pallas"}
+    was = {k: os.environ.get(k) for k in env}
+    os.environ.update(env)
+    try:
+        lowered = jax.jit(step, donate_argnums=(2,)).lower(params, tok, cache,
+                                                           lanes)
+    finally:
+        for k, v in was.items():
+            if v is None:
+                del os.environ[k]
+            else:
+                os.environ[k] = v
+    return cfg, lowered.compile(), kernels.RESOLVED - before
+
+
+def test_mla_decode_step_never_copies_the_latent_cache(deepseek_decode):
+    """The latent rows are written in place into the donated stack and the
+    latent kernel reads them where they lie: no instruction makes a buffer
+    of one layer's latent cache (256 x 4096 x 576 bf16) or more — no copy,
+    slice, convert or allocation — and the whole cache is aliased."""
+    cfg, compiled, _ = deepseek_decode
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    a = cfg.mla
+    layer = MOE_SLOTS * MAX_LEN * (a.kv_lora_rank + a.qk_rope_dim) * 2
+    assert cache_sized_buffers(text, layer) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer, mem
+    assert mem.alias_size_in_bytes >= cfg.n_layers * layer
+
+
+def test_mla_decode_step_reads_in_place_and_runs_the_expert_kernel(
+        deepseek_decode):
+    """5 of 5 latent layers read in place, none sliced; the held experts'
+    matmuls took the Pallas grouped matmul."""
+    cfg, _, took = deepseek_decode
+    assert took[("decode_cache", "in_place")] == cfg.n_layers == 5, took
+    assert took[("decode_cache", "sliced")] == 0, took
+    assert took[("flash_decode", "pallas")] >= 1, took
+    assert took[("expert_gmm", "pallas")] >= 1, took
+    assert not any(impl != "pallas" for (op, impl) in took
+                   if op == "expert_gmm"), took
