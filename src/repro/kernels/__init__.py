@@ -7,8 +7,13 @@ Each kernel package provides:
   ref.py    - pure-jnp oracle used by the test suite
 
 Kernels:
-  flash_attention - blockwise online-softmax attention (calibration forward,
-                    prefill, training) — the dominant non-GEMM compute.
+  flash_attention - attention over whole sequences (calibration forward,
+                    prefill, training, the ViT forward) — the dominant
+                    non-GEMM compute. Bidirectional attention whose
+                    sequences fit VMEM whole (a ViT's 197 tokens) runs one
+                    program per block of sequences with every head in
+                    lanes; causal, windowed or long attention runs
+                    blockwise online softmax over (q, kv) tiles.
   gram            - streaming second-moment (X^T X) accumulation — CORP's
                     calibration statistics hot-spot (Alg. 3/5 inputs).
   wkv6            - RWKV-6 chunked linear-attention recurrence (rwkv6-3b arch).

@@ -1,15 +1,28 @@
-"""Pallas TPU flash attention (forward) with explicit BlockSpec VMEM tiling.
+"""Pallas TPU flash attention (forward), in two block shapes.
 
-Blockwise online-softmax attention over (q_block, kv_block) tiles:
+Whole-sequence path (bidirectional attention whose sequence fits VMEM, as a
+ViT's 197 tokens do): grid ``(B // bb,)``, one program per block of ``bb``
+sequences holding every head. q/k/v are read in their projected layout
+``(B, T, H*d)``, heads folded into lanes, so no transpose or pad is made in
+HBM; the full T and S are the blocks' own dims. Inside, a static loop over
+the heads takes each head's logits over the whole key row, one plain
+softmax (no online rescaling: S is whole), and writes its lanes of the
+output.
+
+Tiled path (everything else: causal or windowed attention, and sequences
+too long for VMEM): blockwise online-softmax over (q_block, kv_block)
+tiles,
   grid = (batch, q_heads, num_q_blocks, num_kv_blocks)  [kv innermost]
   VMEM scratch carries the running (max, denom, accumulator) across the kv
   grid dimension; the output tile is written once on the last kv block.
+The wrapper transposes q/k/v head-major and pads T and S up to a whole
+number of blocks (at most ``bq``/``bk`` rows, multiples of 8); the kernel
+masks the padded keys, so no block spans a whole long sequence (which
+overflows VMEM).
 
-GQA is handled by the k/v index maps (query head h reads kv head h // g).
-Any T and S: the wrapper pads both up to a whole number of blocks (at most
-``bq``/``bk`` rows, multiples of 8) and the kernel masks the padded keys, so
-no block ever spans a whole unaligned sequence (which overflows VMEM at long
-lengths). On CPU it runs under ``interpret=True`` for validation against
+``whole_sequence_block`` picks between them from what a call can see: the
+masking and the shapes. GQA maps query head h to kv head h // g on both
+paths. On CPU both run under ``interpret=True`` for validation against
 ``ref.py``.
 """
 from __future__ import annotations
@@ -77,6 +90,114 @@ def _block(n: int, cap: int) -> int:
     return cap if n > cap else -(-n // 8) * 8
 
 
+# VMEM the whole-sequence path may plan for: every block double-buffered
+# plus one head's logits and their softmax temporaries, within the 16 MiB
+# of scoped VMEM a v5e kernel gets by default.
+WHOLE_VMEM_BYTES = 12 << 20
+
+
+def _tile_bytes(rows: int, lanes: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, lanes) block: rows round up to the sublane
+    tile (8 rows of 32 bits), lanes to 128."""
+    sub = 32 // itemsize
+    return -(-rows // sub) * sub * (-(-lanes // 128) * 128) * itemsize
+
+
+def whole_sequence_block(q_shape, k_shape, v_shape, dtype, *, causal,
+                         window) -> int:
+    """Sequences per program of the whole-sequence path: the largest
+    divisor of the batch whose blocks fit ``WHOLE_VMEM_BYTES``; 0 where
+    the path does not apply (causal or windowed masks, or one sequence too
+    long), and the tiled kernel runs."""
+    if causal or window is not None:
+        return 0
+    B, T, H, dq = q_shape
+    S, Hkv, dv = k_shape[1], k_shape[2], v_shape[-1]
+    n = jnp.dtype(dtype).itemsize
+    seq = 2 * (_tile_bytes(T, H * dq, n) + _tile_bytes(S, Hkv * dq, n)
+               + _tile_bytes(S, Hkv * dv, n) + _tile_bytes(T, H * dv, n))
+    logits = 3 * _tile_bytes(T, S, 4)
+    fits = [bb for bb in range(1, B + 1)
+            if B % bb == 0 and bb * seq + logits <= WHOLE_VMEM_BYTES]
+    return max(fits, default=0)
+
+
+def _whole_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, g, dq, dv):
+    """One block of whole sequences, every head: q (bb, T, H*dq), k
+    (bb, S, Hkv*dq), v (bb, S, Hkv*dv) -> o (bb, T, H*dv).
+
+    Without GQA, and where no head straddles a 128-lane group, each head
+    reads the aligned group that holds it: its q lanes kept and the other
+    heads' zeroed, which adds exact zeros to the contraction (a 32-deep
+    contraction costs the MXU a 128-deep pass anyway), and its output
+    lanes selected into the group, which is stored once. Otherwise each
+    head reads and writes its own lanes."""
+    grouped = g == 1 and 128 % dq == 0 and 128 % dv == 0
+
+    def lanes(ref, i, lo, d):
+        """Lanes of ``ref[i]`` holding [lo, lo + d): (the block, its first
+        lane, the mask of [lo, lo + d) in it or None where it is exact)."""
+        if not grouped:
+            return ref[i, :, pl.ds(lo, d)].astype(jnp.float32), lo, None
+        a = lo // 128 * 128
+        x = ref[i, :, a:min(a + 128, ref.shape[-1])].astype(jnp.float32)
+        lane = a + jax.lax.broadcasted_iota(jnp.int32, (1, x.shape[1]), 1)
+        return x, a, (lane >= lo) & (lane < lo + d)
+
+    def sequence(i, carry):
+        groups = {}                                       # first lane -> o
+        for h in range(q_ref.shape[-1] // dq):            # heads, static
+            n = h // g
+            q, _, own = lanes(q_ref, i, h * dq, dq)
+            if own is not None:
+                q = jnp.where(own, q, 0.0)
+            k, _, _ = lanes(k_ref, i, n * dq, dq)
+            logits = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (T, S)
+            p = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
+            v, a, own = lanes(v_ref, i, n * dv, dv)
+            o = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            o = o / jnp.sum(p, axis=-1, keepdims=True)
+            if own is None:
+                o_ref[i, :, pl.ds(h * dv, dv)] = o.astype(o_ref.dtype)
+            else:
+                groups[a] = jnp.where(own, o, groups.get(a, 0.0))
+        for a, o in groups.items():
+            o_ref[i, :, a:a + o.shape[-1]] = o.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[0], sequence, 0)
+
+
+def _whole_sequence(q, k, v, *, scale, bb, interpret):
+    """The whole-sequence path: heads fold into lanes by reshapes that
+    keep the projected layout, and one program covers ``bb`` sequences."""
+    B, T, H, dq = q.shape
+    S, Hkv, dv = k.shape[1], k.shape[2], v.shape[-1]
+    kernel = functools.partial(_whole_kernel, scale=scale, g=H // Hkv,
+                               dq=dq, dv=dv)
+    out = pl.pallas_call(
+        kernel,
+        grid=(B // bb,),
+        in_specs=[
+            pl.BlockSpec((bb, T, H * dq), lambda b: (b, 0, 0)),
+            pl.BlockSpec((bb, S, Hkv * dq), lambda b: (b, 0, 0)),
+            pl.BlockSpec((bb, S, Hkv * dv), lambda b: (b, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((bb, T, H * dv), lambda b: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, T, H * dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="flash_attention_whole_sequence",
+    )(q.reshape(B, T, H * dq), k.reshape(B, S, Hkv * dq),
+      v.reshape(B, S, Hkv * dv))
+    return out.reshape(B, T, H, dv)
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
                                              "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
@@ -88,6 +209,11 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
     g = H // Hkv
     if scale is None:
         scale = float(1.0 / np.sqrt(dq))
+    bb = whole_sequence_block(q.shape, k.shape, v.shape, q.dtype,
+                              causal=causal, window=window)
+    if bb:
+        return _whole_sequence(q, k, v, scale=scale, bb=bb,
+                               interpret=interpret)
     bq, bk = _block(T, bq), _block(S, bk)
     Tp, Sp = -(-T // bq) * bq, -(-S // bk) * bk
     nq, nk = Tp // bq, Sp // bk

@@ -1,7 +1,17 @@
 """Public attention op with backend dispatch.
 
 impl resolution (env ``REPRO_ATTN_IMPL`` overrides):
-  * 'pallas'  : Pallas TPU kernel (forward) — selected on TPU backends.
+  * 'pallas'  : Pallas TPU kernel (forward) — selected on TPU backends. It
+                runs one of two block shapes, picked from the call's
+                masking and lengths (``whole_sequence_block``):
+                whole-sequence for bidirectional, unwindowed attention
+                whose sequences fit VMEM whole (ViT, short
+                cross-attention: one program per block of sequences, all
+                heads, q/k/v read in their projected layout), tiled
+                online softmax for everything else (causal or windowed
+                prefill, long sequences). Each call records its path as
+                ``attention_blocks:whole_sequence`` or ``:tiled`` in
+                ``kernels.RESOLVED``.
   * 'xla'     : memory-sane chunked online-softmax attention in pure jnp
                 (lax.scan over q- and kv-chunks) — selected on CPU/GPU and
                 used for all dry-run lowering. Never materializes the full
@@ -12,7 +22,6 @@ impl resolution (env ``REPRO_ATTN_IMPL`` overrides):
 """
 from __future__ import annotations
 
-import functools
 import os
 
 import jax
@@ -21,7 +30,8 @@ import numpy as np
 
 from repro.kernels import note_impl, per_device
 from repro.kernels.flash_attention import ref as _ref
-from repro.kernels.flash_attention.flash_attention import flash_attention
+from repro.kernels.flash_attention.flash_attention import (
+    flash_attention, whole_sequence_block)
 
 _SMALL = 1 << 20  # T*S below this: just use the oracle
 
@@ -49,10 +59,16 @@ def attention(q, k, v, *, causal=True, window=None, scale=None,
         return _ref.attention(q, k, v, causal=causal, window=window,
                               scale=scale)
     if impl in ("pallas", "interpret"):
-        # the kernel pads T/S to whole 128-row blocks itself
-        kern = functools.partial(flash_attention, causal=causal,
-                                 window=window, scale=scale,
-                                 interpret=(impl == "interpret"))
+        def kern(q, k, v):
+            # the kernel picks its path from the shapes one device holds
+            whole = whole_sequence_block(q.shape, k.shape, v.shape, q.dtype,
+                                         causal=causal, window=window)
+            note_impl("attention_blocks",
+                      "whole_sequence" if whole else "tiled")
+            return flash_attention(q, k, v, causal=causal, window=window,
+                                   scale=scale,
+                                   interpret=(impl == "interpret"))
+
         heads = ("batch", None, "model", None)
         return per_device(kern, (q, k, v), (heads,) * 3, heads)
     return _chunked(q, k, v, causal=causal, window=window, scale=scale,

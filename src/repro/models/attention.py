@@ -125,16 +125,24 @@ def mla_scale(cfg) -> float:
 # full-sequence attention (train / prefill)
 # ---------------------------------------------------------------------------
 
-def _project_qkv(p, x, cfg, positions, kind, taps):
-    """Common Q/K/V projection + bias + qk-norm + rope + tap."""
+def _project_qkv(p, x, cfg, positions, kind, taps, folded=False):
+    """Common Q/K/V projection + bias + qk-norm + rope + tap.
+
+    ``folded``: project each of q/k/v as one (D, H*d) matmul
+    (``project_folded``), for bidirectional attention; causal callers keep
+    the per-head einsums."""
     dt = x.dtype
-    q = jnp.einsum("btd,dhq->bthq", x, p["wq"])
-    k = jnp.einsum("btd,dhq->bthq", x, p["wk"])
-    v = jnp.einsum("btd,dhv->bthv", x, p["wv"])
-    if "bq" in p:
-        q = q + p["bq"].astype(dt)
-        k = k + p["bk"].astype(dt)
-        v = v + p["bv"].astype(dt)
+    if folded:
+        q, k, v = (project_folded(x, p[w], p.get(b))
+                   for w, b in (("wq", "bq"), ("wk", "bk"), ("wv", "bv")))
+    else:
+        q = jnp.einsum("btd,dhq->bthq", x, p["wq"])
+        k = jnp.einsum("btd,dhq->bthq", x, p["wk"])
+        v = jnp.einsum("btd,dhv->bthv", x, p["wv"])
+        if "bq" in p:
+            q = q + p["bq"].astype(dt)
+            k = k + p["bk"].astype(dt)
+            v = v + p["bv"].astype(dt)
     if "q_scale" in p:
         q = rms_head_norm(q, p["q_scale"], cfg.norm_eps)
         k = rms_head_norm(k, p["k_scale"], cfg.norm_eps)
@@ -145,6 +153,24 @@ def _project_qkv(p, x, cfg, positions, kind, taps):
     tap(taps, "q", q)
     tap(taps, "k", k)
     return q, k, v
+
+
+def project_folded(x, w, b=None):
+    """x (B, T, D) @ w (D, H, d) [+ b (H, d)] -> (B, T, H, d) as one
+    (D, H*d) matmul: the heads leave it folded into lanes, the
+    ``(B, T, H*d)`` layout the whole-sequence attention kernel reads, so
+    the reshapes on either side of the kernel stay bitcasts."""
+    y = jnp.einsum("btd,dk->btk", x, w.reshape(w.shape[0], -1))
+    if b is not None:
+        y = y + b.reshape(-1).astype(y.dtype)
+    return y.reshape(y.shape[:2] + w.shape[1:])
+
+
+def output_folded(o, wo):
+    """o (B, T, H, dv) @ wo (H, dv, D) -> (B, T, D), read with the heads
+    folded into lanes as the whole-sequence kernel writes them."""
+    return jnp.einsum("btk,kd->btd", o.reshape(o.shape[:2] + (-1,)),
+                      wo.reshape(-1, wo.shape[-1]))
 
 
 def _rope_gathered(x, positions, inv):
@@ -167,12 +193,14 @@ def apply_attn(p, x, cfg, kind, *, positions, taps=None, return_cache=False,
     if cfg.mla is not None and "w_dq" in p:
         return _apply_mla(p, x, cfg, positions=positions, taps=taps,
                           return_cache=return_cache)
-    q, k, v = _project_qkv(p, x, cfg, positions, kind, taps)
-    window = cfg.sliding_window if (kind == "swa" and mask_kind != "full") else None
+    full = mask_kind == "full"
+    q, k, v = _project_qkv(p, x, cfg, positions, kind, taps, folded=full)
+    window = cfg.sliding_window if (kind == "swa" and not full) else None
     scale = 1.0 / np.sqrt(cfg.qk_full if cfg.qk_kept is None else cfg.qk_full)
-    o = flash_ops.attention(q, k, v, causal=(mask_kind != "full"),
-                            window=window, scale=scale)
-    y = jnp.einsum("bthv,hvd->btd", o, p["wo"])
+    o = flash_ops.attention(q, k, v, causal=not full, window=window,
+                            scale=scale)
+    y = (output_folded(o, p["wo"]) if full
+         else jnp.einsum("bthv,hvd->btd", o, p["wo"]))
     cache = None
     if return_cache:
         cache = {"k": fold_heads(k), "v": fold_heads(v),
@@ -226,19 +254,14 @@ def _apply_mla(p, x, cfg, *, positions, taps=None, return_cache=False):
 
 def apply_cross_attn(p, x, mem, cfg, *, taps=None):
     """x: (B, T, D) decoder states; mem: (B, S, D) encoder memory."""
-    dt = x.dtype
-    q = jnp.einsum("btd,dhq->bthq", x, p["wq"])
-    k = jnp.einsum("bsd,dhq->bshq", mem, p["wk"])
-    v = jnp.einsum("bsd,dhv->bshv", mem, p["wv"])
-    if "bq" in p:
-        q = q + p["bq"].astype(dt)
-        k = k + p["bk"].astype(dt)
-        v = v + p["bv"].astype(dt)
+    q = project_folded(x, p["wq"], p.get("bq"))
+    k = project_folded(mem, p["wk"], p.get("bk"))
+    v = project_folded(mem, p["wv"], p.get("bv"))
     tap(taps, "q", q)
     tap(taps, "k", k)
     scale = 1.0 / np.sqrt(cfg.qk_full)
     o = flash_ops.attention(q, k, v, causal=False, scale=scale)
-    return jnp.einsum("bthv,hvd->btd", o, p["wo"])
+    return output_folded(o, p["wo"])
 
 
 def precompute_cross_cache(p, mem, cfg):

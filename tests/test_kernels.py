@@ -44,6 +44,99 @@ def test_flash_vs_ref(b, t, s, h, hkv, dq, dv, dtype, causal, window):
                                rtol=tol)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("b,t,s,h,hkv,dq,dv,blocks", [
+    (2, 197, 197, 12, 12, 32, 64, "whole_sequence"),  # DeiT-Base, CORP qk 32
+    (2, 197, 197, 12, 12, 64, 64, "whole_sequence"),  # DeiT-Base, dense
+    (3, 197, 197, 12, 12, 64, 64, "whole_sequence"),  # odd batch, 3 programs
+    (2, 100, 120, 8, 2, 32, 64, "whole_sequence"),    # GQA, cross lengths
+    (2, 64, 80, 4, 4, 48, 96, "whole_sequence"),      # heads straddle lanes
+    (2, 16, 24, 2, 2, 32, 32, "whole_sequence"),      # under 128 lanes
+    (1, 512, 2048, 2, 2, 64, 64, "tiled"),            # over the VMEM budget
+])
+def test_flash_bidirectional_vs_ref(b, t, s, h, hkv, dq, dv, blocks, dtype):
+    """Bidirectional attention through the Pallas kernel: a sequence that
+    fits VMEM whole takes the whole-sequence path, a longer one the tiled
+    path; both match the oracle."""
+    from repro import kernels
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (b, t, h, dq), dtype)
+    k = jax.random.normal(ks[1], (b, s, hkv, dq), dtype)
+    v = jax.random.normal(ks[2], (b, s, hkv, dv), dtype)
+    before = kernels.RESOLVED.copy()
+    pal = fops.attention(q, k, v, causal=False, impl="interpret")
+    assert kernels.RESOLVED - before == {("flash_attention", "interpret"): 1,
+                                         ("attention_blocks", blocks): 1}
+    ref = fref.attention(q, k, v, causal=False)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(pal, np.float32),
+                               np.asarray(ref, np.float32), atol=tol,
+                               rtol=tol)
+
+
+def _attention_blocks(q, k, v, **mask):
+    """The ``attention_blocks`` path one Pallas call traces to."""
+    from repro import kernels
+    before = kernels.RESOLVED.copy()
+    jax.eval_shape(lambda q, k, v: fops.attention(q, k, v, impl="pallas",
+                                                  **mask), q, k, v)
+    took = kernels.RESOLVED - before
+    (path,) = [p for op, p in took if op == "attention_blocks"]
+    return path
+
+
+def test_attention_blocks_follow_the_shape():
+    """Masking and lengths pick the path: causal and windowed prefills
+    (qwen2 chat, DeepSeek-V3 MLA) stay tiled; DeiT's bidirectional 197
+    tokens run whole, two images a program at the classify batch."""
+    from repro.kernels.flash_attention.flash_attention import (
+        whole_sequence_block)
+    sd = jax.ShapeDtypeStruct
+    qwen = (sd((1, 512, 12, 64), jnp.bfloat16),
+            sd((1, 512, 2, 64), jnp.bfloat16),
+            sd((1, 512, 2, 128), jnp.bfloat16))
+    assert _attention_blocks(*qwen, causal=True) == "tiled"
+    assert _attention_blocks(*qwen, causal=True, window=256) == "tiled"
+    mla = (sd((1, 256, 128, 192), jnp.bfloat16),) * 2 + (
+        sd((1, 256, 128, 128), jnp.bfloat16),)
+    assert _attention_blocks(*mla, causal=True) == "tiled"
+    deit = (sd((256, 197, 12, 32), jnp.float32),) * 2 + (
+        sd((256, 197, 12, 64), jnp.float32),)
+    assert _attention_blocks(*deit, causal=False) == "whole_sequence"
+    assert _attention_blocks(*deit, causal=True) == "tiled"
+    assert whole_sequence_block(*(x.shape for x in deit), jnp.float32,
+                                causal=False, window=None) == 2
+    long = (sd((1, 4096, 8, 64), jnp.float32),) * 3
+    assert _attention_blocks(*long, causal=False) == "tiled"
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and its sub-jaxprs, kernel bodies
+    excluded."""
+    for e in jaxpr.eqns:
+        yield e
+        if e.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
+def test_whole_sequence_is_one_kernel_on_q_k_v_in_place():
+    """A DeiT-shaped call is one ``pallas_call`` named for the benchmark's
+    ``flash_attention`` pattern, fed q/k/v by reshapes alone: no pad or
+    transpose of them is left."""
+    q = jnp.zeros((4, 197, 12, 32))
+    v = jnp.zeros((4, 197, 12, 64))
+    jaxpr = jax.make_jaxpr(lambda q, k, v: fops.attention(
+        q, k, v, causal=False, impl="pallas"))(q, q, v).jaxpr
+    eqns = list(_eqns(jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert "flash_attention" in calls[0].params["name"]
+    names = {e.primitive.name for e in eqns}
+    assert not names & {"pad", "transpose", "concatenate"}, names
+
+
 def test_flash_xla_grad_matches_ref_grad():
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (1, 128, 2, 16))

@@ -90,12 +90,56 @@ def test_gram_cross_shard_local(one_chip):
 @pytest.mark.parametrize("dq", [64, 32])
 def test_flash_attention_deit_base(one_chip, dq):
     """deit-base attention (197 tokens, 12 heads), dense and CORP-pruned
-    qk (dq 32); 197 pads to two 128-row blocks."""
+    qk (dq 32): the whole-sequence path, one kernel whose blocks hold all
+    197 tokens, so nothing pads q/k/v."""
     q = sds((8, 197, 12, dq), "float32", one_chip)
     v = sds((8, 197, 12, 64), "float32", one_chip)
-    assert "tpu_custom_call" in compiled_text(
+    text = compiled_text(
         lambda q, k, v: fops.attention(q, k, v, causal=False, impl="pallas"),
         q, q, v)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert not [op for op in _instructions(text) if op[3] == "pad"]
+
+
+def test_vit_attention_reads_the_projections_in_place(one_chip):
+    """deit-base-corp50's attention half at a batch of 8: q/k/v leave
+    their projections in the layout the whole-sequence kernel reads and
+    its output feeds the output projection, with no pad, transpose or
+    copy of them in between."""
+    import json
+    from repro.configs.base import ModelConfig
+    from repro.models import attention
+    m = json.load(open(DEIT_BASE_CORP50))["model"]
+    cfg = ModelConfig(**m)
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: attention.init_attn(jax.random.PRNGKey(0),
+                                                   cfg, "attn")))
+    x = sds((8, 197, cfg.d_model), "float32", one_chip)
+
+    def attn(p, x):
+        return attention.apply_attn(p, x, cfg, "attn", positions=None,
+                                    mask_kind="full")[0]
+
+    was = os.environ.get("REPRO_ATTN_IMPL")
+    os.environ["REPRO_ATTN_IMPL"] = "pallas"
+    try:
+        text = compiled_text(attn, params, x)
+    finally:
+        if was is None:
+            del os.environ["REPRO_ATTN_IMPL"]
+        else:
+            os.environ["REPRO_ATTN_IMPL"] = was
+    ops = {op[1]: op for op in _instructions(text)}
+    (kern,) = [op for op in ops.values() if "tpu_custom_call" in op[5]]
+
+    def matmul(op):                      # a dot, alone or as a fusion's root
+        return op[3] == "convolution" or "kind=kOutput" in op[5]
+
+    feeds = [ops[name] for name in kern[4]]
+    users = [op for op in ops.values() if kern[1] in op[4]]
+    assert len(feeds) == 3 and all(map(matmul, feeds)), feeds
+    assert users and all(map(matmul, users)), users
 
 
 def test_flash_attention_causal_unaligned(one_chip):
@@ -120,6 +164,8 @@ def test_flash_decode_qwen2(one_chip):
 
 # --- the served decode step at the chat cell's size -------------------------
 
+DEIT_BASE_CORP50 = os.path.join(os.path.dirname(__file__), "..", "bench",
+                                "configs", "deit-base-corp50.json")
 QWEN2_CORP50 = os.path.join(os.path.dirname(__file__), "..", "bench",
                             "configs", "qwen2-1.5b-corp50.json")
 SLOTS, MAX_LEN = 64, 4096
@@ -289,6 +335,20 @@ def test_kernels_per_device_on_four_chips(mesh):
             return (dops.decode_attention(q, k, v, m, impl="pallas"),
                     fops.attention(s, s, s, causal=True, impl="pallas"))
     assert "tpu_custom_call" in compiled_text(step, q, kv, kv, valid, seq)
+
+
+def test_whole_sequence_attention_per_device_on_four_chips(mesh):
+    """deit-base-corp50 attention in a program over four chips: the 12
+    heads split over the model axis, and each chip's whole-sequence
+    kernel folds its 3 heads into lanes (96 of q/k, 192 of v)."""
+    heads = NamedSharding(mesh, P(None, None, "model", None))
+    qk = sds((8, 197, 12, 32), "float32", heads)
+    v = sds((8, 197, 12, 64), "float32", heads)
+
+    def step(q, k, v):
+        with kernel_mesh(mesh):
+            return fops.attention(q, k, v, causal=False, impl="pallas")
+    assert "tpu_custom_call" in compiled_text(step, qk, qk, v)
 
 
 # --- the served decode step at the reasoning cell's size ---------------------
