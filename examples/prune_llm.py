@@ -10,14 +10,13 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from repro.core import PruneConfig, corp_prune  # noqa: E402
 from repro.models import build_model  # noqa: E402
 
 
 def main():
-    from benchmarks.common import calib_lm, lm_eval_ppl, trained_lm
+    from trained import calib_lm, lm_eval_ppl, trained_lm
     cfg, model, params = trained_lm()
     print(f"dense ppl = {lm_eval_ppl(model, params):.3f}")
     for tag, (sm, sa) in {"mlp": (0.3, 0.0), "attn": (0.0, 0.3),

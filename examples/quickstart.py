@@ -19,16 +19,13 @@ activation statistics without pruning::
     stats = engine.run(params, calib_batches())   # {unit: {n, s1, s2, na}}
 
 Long passes checkpoint + resume via ``corp_prune(..., ckpt_dir=...)``
-(see repro.distrib.fault.CalibrationCheckpointer), and
-``benchmarks/bench_calibration.py`` tracks fused-vs-per-unit-loop
-throughput.
+(see repro.distrib.fault.CalibrationCheckpointer).
 """
 import argparse
 import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax  # noqa: E402
 
@@ -42,13 +39,12 @@ def main():
     ap.add_argument("--sparsity", type=float, default=0.875,
                     help="paper Fig. 2: the compensation gap grows with sparsity")
     args = ap.parse_args()
-    os.environ["BENCH_VIT_STEPS"] = str(args.steps)
 
-    from benchmarks.common import (calib_vit, forward_flops, params_of,
-                                   trained_vit, vit_eval_acc)
+    from trained import (calib_vit, forward_flops, params_of, trained_vit,
+                         vit_eval_acc)
 
-    print("== 1. train (cached under benchmarks/_cache) ==")
-    cfg, model, params = trained_vit()
+    print("== 1. train (cached under examples/_cache) ==")
+    cfg, model, params = trained_vit(args.steps)
     acc0 = vit_eval_acc(model, params)
     p0 = params_of(params)
     print(f"dense model: top1={acc0:.4f} params={p0/1e3:.0f}k")
@@ -69,8 +65,8 @@ def main():
     print("== 4. results ==")
     b = {"images": jax.ShapeDtypeStruct((16, cfg.img_size, cfg.img_size, 3),
                                         jax.numpy.float32)}
-    f0 = forward_flops(model, cfg, b)
-    f1 = forward_flops(m2, pcfg, b)
+    f0 = forward_flops(model, b)
+    f1 = forward_flops(m2, b)
     print(f"dense   : top1={acc0:.4f}  params={p0/1e3:7.0f}k  flops=1.00x")
     print(f"CORP    : top1={acc1:.4f}  params={params_of(pruned)/1e3:7.0f}k"
           f"  flops={f1/f0:.2f}x")

@@ -11,7 +11,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from repro.core import PruneConfig, corp_prune  # noqa: E402
 from repro.models import build_model  # noqa: E402
@@ -38,7 +37,7 @@ def main():
     ap.add_argument("--sparsity", type=float, default=0.5)
     args = ap.parse_args()
 
-    from benchmarks.common import calib_lm, trained_lm
+    from trained import calib_lm, trained_lm
     cfg, model, params = trained_lm()
     prompt_range, gen_range = (8, 48), (4, 48)
     trace = synthetic_trace(args.requests, cfg.vocab_size, seed=0,

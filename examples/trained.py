@@ -1,14 +1,13 @@
-"""Shared benchmark fixtures: small trained models (cached across tables).
+"""Small trained models shared by the examples (cached under examples/_cache).
 
-The paper's experiments need *trained* networks (random weights have nearly
-isotropic activations — App. A's redundancy only exists after training), so
-each benchmark reuses a DeiT-family ViT and a markov-LM trained for a few
-hundred CPU steps and cached under benchmarks/_cache.
+CORP needs *trained* networks (random weights have nearly isotropic
+activations — App. A's redundancy only exists after training), so the
+examples reuse a DeiT-family ViT and a markov-LM trained for a few hundred
+CPU steps, cached after the first run.
 """
 from __future__ import annotations
 
 import os
-import time
 
 import jax
 import jax.numpy as jnp
@@ -22,31 +21,27 @@ from repro.models import build_model
 
 CACHE = os.path.join(os.path.dirname(__file__), "_cache")
 
-VIT_STEPS = int(os.environ.get("BENCH_VIT_STEPS", "300"))
-LM_STEPS = int(os.environ.get("BENCH_LM_STEPS", "300"))
-
-
 VIT_TASK = {"noise": 2.0, "iid_noise": 0.5, "n_classes": 32}
 
 
-def bench_vit_cfg():
+def vit_cfg():
     return reduced(get_config("deit-base")).replace(
-        name="deit-bench", n_layers=4, d_model=96, n_heads=4, n_kv_heads=4,
-        d_head=24, d_ff=384, img_size=32, patch=8,
+        name="deit-example", n_layers=4, d_model=96, n_heads=4,
+        n_kv_heads=4, d_head=24, d_ff=384, img_size=32, patch=8,
         n_classes=VIT_TASK["n_classes"])
 
 
 def vit_task_batch(step: int, batch: int, img: int):
-    """The benchmark vision task (difficulty tuned so 50-70% naive pruning
+    """The example vision task (difficulty tuned so 50-70% naive pruning
     visibly hurts while the dense model sits near ~80%)."""
     return vit_batch(step, batch=batch, img=img,
                      n_classes=VIT_TASK["n_classes"], seed=0,
                      noise=VIT_TASK["noise"], iid_noise=VIT_TASK["iid_noise"])
 
 
-def bench_lm_cfg():
+def lm_cfg():
     return reduced(get_config("qwen2-1.5b")).replace(
-        name="lm-bench", n_layers=4, d_model=96, n_heads=4, n_kv_heads=2,
+        name="lm-example", n_layers=4, d_model=96, n_heads=4, n_kv_heads=2,
         d_head=24, d_ff=384, vocab_size=256, dtype="float32")
 
 
@@ -79,16 +74,16 @@ def _cached_train(tag, cfg, steps, batch, seq=48, data_fn=None):
     return model, params
 
 
-def trained_vit():
-    cfg = bench_vit_cfg()
+def trained_vit(steps: int = 300):
+    cfg = vit_cfg()
     return cfg, *_cached_train(
-        "vit", cfg, VIT_STEPS, batch=64,
+        "vit", cfg, steps, batch=64,
         data_fn=lambda s, b: vit_task_batch(s, b, cfg.img_size))
 
 
-def trained_lm():
-    cfg = bench_lm_cfg()
-    return cfg, *_cached_train("lm", cfg, LM_STEPS, batch=16, seq=48)
+def trained_lm(steps: int = 300):
+    cfg = lm_cfg()
+    return cfg, *_cached_train("lm", cfg, steps, batch=16, seq=48)
 
 
 # ---------------------------------------------------------------------------
@@ -142,11 +137,7 @@ def calib_lm(cfg, n_samples=64, batch=8, seq=48):
     return make
 
 
-# ---------------------------------------------------------------------------
-# flops / timing
-# ---------------------------------------------------------------------------
-
-def forward_flops(model, cfg, batch):
+def forward_flops(model, batch):
     from repro.roofline.analysis import jaxpr_matmul_flops
     return jaxpr_matmul_flops(lambda p, b: model.apply(p, b),
                               jax.eval_shape(lambda: model.init(
@@ -155,17 +146,3 @@ def forward_flops(model, cfg, batch):
 
 def params_of(params) -> int:
     return int(sum(x.size for x in jax.tree.leaves(params)))
-
-
-def timeit(fn, *args, warmup=2, iters=10):
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = fn(*args)
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / iters
-
-
-def row(name: str, us_per_call: float, derived: str):
-    print(f"{name},{us_per_call:.1f},{derived}", flush=True)

@@ -2,7 +2,7 @@
 
 Plain ViT: LayerNorm, GELU two-matrix MLP, learned positional embeddings,
 cls token, classification head. Used for the faithful CORP reproduction,
-benchmarks and examples.
+the classify benchmark cell and the examples.
 """
 from repro.configs.base import ModelConfig
 

@@ -35,7 +35,7 @@ claim holds. ``CalibrationEngine`` owns that hot path:
     model's activation taps in bf16 and streams them into the gram kernel
     as-is, halving calibration HBM traffic; every accumulator stays fp32
     (the kernel casts per tile inside VMEM). Sigma tolerance vs the fp32
-    stream is gated in ``benchmarks/bench_calibration.py`` and studied in
+    stream is tested in ``tests/test_bf16_streaming.py`` and studied in
     docs/kernels.md;
   * **mesh-sharded** — pass ``mesh=`` and the fused step runs under pjit
     with an explicit sharding for every statistic leaf
@@ -64,9 +64,8 @@ Usage::
 Every statistic is a linear reduction, so the sharded engine is bitwise a
 partitioning of the single-device one (same sums, same order per shard);
 ``tests/test_sharded_calibration.py`` asserts fp32 parity on a forced
-4-device host mesh. ``benchmarks/bench_calibration.py`` records
-fused-vs-per-unit-loop throughput and ``benchmarks/bench_calib_sharded.py``
-the sharded engine's per-device Sigma footprint.
+4-device host mesh, and that each device holds fewer statistic bytes than
+the single-device engine.
 """
 from __future__ import annotations
 
@@ -122,7 +121,7 @@ class CalibrationEngine:
         statistic still accumulates in fp32 — the gram kernel casts tiles
         inside VMEM, the other reductions cast at their inputs — so only
         the per-tap rounding differs (docs/kernels.md quantifies the Sigma
-        tolerance; benchmarks/bench_calibration.py gates it).
+        tolerance; tests/test_bf16_streaming.py holds it).
 
     Attributes:
       fingerprint: hash of what this engine accumulates (phase, unit set,

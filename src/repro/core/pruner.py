@@ -75,8 +75,8 @@ def accumulate(step_fn: Callable, params, batches: Iterable) -> Dict:
     """Legacy host-side accumulation loop (one jitted step, tree-add on the
     host per batch). The pipeline itself uses CalibrationEngine's fused
     donated-accumulator step; this stays as the reference implementation
-    for parity tests and the loop-vs-fused benchmark
-    (benchmarks/bench_calibration.py)."""
+    the engine's parity tests compare with
+    (tests/test_calibration_engine.py)."""
     total = None
     jit_step = jax.jit(step_fn)
     for batch in batches:

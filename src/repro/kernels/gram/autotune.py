@@ -23,7 +23,7 @@ Choices are cached per (N, F, dtype, budget) — the calibration hot loop
 re-resolves tiles every batch, and calibration streams have constant
 shapes. Because the fixed legacy default (128, 512) is always in the
 candidate set, the autotuned pick is never *predicted* slower than it
-(gated in benchmarks/bench_calibration.py).
+(tests/test_gram_autotune.py).
 
 Examples (doctested in CI):
 

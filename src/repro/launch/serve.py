@@ -1,16 +1,10 @@
-"""Serving driver: fixed-batch baseline loop + continuous-batching engine.
-
-Fixed-batch (the pre-engine baseline, kept for comparison):
-
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b-reduced \
-        --batch 4 --prompt-len 32 --gen 16
-
-Continuous batching over a synthetic ragged arrival trace, driven through
-the async front-end (bounded queue, deadlines, prefix cache — reports
-per-status counts plus p50/p99 latency/ttft; see docs/serving.md):
+"""Serving driver: continuous batching over a synthetic ragged arrival
+trace, driven through the async front-end (bounded queue, deadlines,
+prefix cache — reports per-status counts plus p50/p99 latency/ttft; see
+docs/serving.md):
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b-reduced \
-        --trace 24 --slots 4 --max-len 128 --compare-static
+        --trace 24 --slots 4 --max-len 128
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b-reduced \
         --trace 32 --slots 4 --rate 20 --queue-depth 8 \
@@ -26,8 +20,6 @@ import argparse
 import time
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.checkpoint import latest_step, restore_checkpoint
 from repro.launch.compile_cache import use_compile_cache
@@ -35,57 +27,8 @@ from repro.launch.train import resolve_config
 from repro.models import build_model
 
 
-def serve_loop(model, params, *, batch, prompt_len, gen, max_len,
-               seed=0, log=print):
-    """Fixed-batch prefill + greedy decode; returns exactly ``gen`` tokens
-    per request: the prefill argmax plus ``gen - 1`` decode steps, each of
-    which is inside the timed region (the old loop ran one extra decode step
-    whose token was discarded, so the stream was shifted off the timing)."""
-    cfg = model.cfg
-    rng = np.random.RandomState(seed)
-    toks = jnp.asarray(rng.randint(0, cfg.vocab_size,
-                                   size=(batch, prompt_len)), jnp.int32)
-    req = {"tokens": toks}
-    if cfg.family == "encdec":
-        req["frames"] = jnp.asarray(
-            rng.randn(batch, prompt_len, cfg.d_model).astype(np.float32))
-
-    prefill = jax.jit(lambda p, b: model.prefill(p, b, max_len))
-    decode = jax.jit(model.decode_step)
-
-    def argmax(logits):
-        return jnp.argmax(logits[:, -1, : cfg.vocab_size],
-                          axis=-1)[:, None].astype(jnp.int32)
-
-    # warm up (compile) outside the timed region
-    logits, cache = prefill(params, req)
-    _l, _c = decode(params, argmax(logits), cache)
-    jax.block_until_ready(_l)
-
-    t0 = time.time()
-    logits, cache = prefill(params, req)
-    jax.block_until_ready(logits)
-    t_prefill = time.time() - t0
-
-    tok = argmax(logits)          # first generated token (from prefill)
-    out_tokens = [tok]
-    t0 = time.time()
-    for _ in range(gen - 1):
-        logits, cache = decode(params, tok, cache)
-        tok = argmax(logits)
-        out_tokens.append(tok)
-    jax.block_until_ready(tok)
-    t_decode = time.time() - t0
-    steps = gen - 1
-    log(f"[serve] prefill {t_prefill*1e3:.1f} ms "
-        f"({batch}x{prompt_len} tokens); decode "
-        f"{steps} steps in {t_decode*1e3:.1f} ms -> "
-        f"{batch*steps/max(t_decode,1e-9):.1f} tok/s")
-    return jnp.concatenate(out_tokens, axis=1), t_prefill, t_decode
-
-
 def serve_trace(model, params, *, n, slots, max_len, prompt_range, gen_range,
-                rate=None, seed=0, compare_static=False, queue_depth=16,
+                rate=None, seed=0, queue_depth=16,
                 deadline_ms=None, deadline_frac=1.0, prefix_cache=0,
                 prefix_len=0, spf=False, replicas=1, route="least-loaded",
                 mem_len=None, sharding=None, prefill_chunk=None, log=print):
@@ -118,7 +61,6 @@ def serve_trace(model, params, *, n, slots, max_len, prompt_range, gen_range,
     """
     from repro.serve import (PrefixCache, ReplicaRouter, ServeEngine,
                              ServeFrontend, frontend_table,
-                             percentile_table, run_static_trace,
                              synthetic_trace)
     from repro.serve.engine import format_table
     cfg = model.cfg
@@ -150,7 +92,6 @@ def serve_trace(model, params, *, n, slots, max_len, prompt_range, gen_range,
     wall = time.perf_counter() - t0
     table = frontend_table(handles, wall)
     table["mode"] = f"fleet-x{replicas}" if replicas > 1 else "frontend"
-    rows = [table]
     log(f"[serve] frontend: {eng.stats['admits']} admits, "
         f"{eng.stats['decode_steps']} decode steps, "
         f"lane utilization "
@@ -170,26 +111,16 @@ def serve_trace(model, params, *, n, slots, max_len, prompt_range, gen_range,
                 log(f"[serve] replica {i} prefix cache: {st}")
     if pc is not None:
         log(f"[serve] prefix cache: {pc.stats()}")
-    if compare_static:
-        # run_static_trace compile-warms internally; time from its clock
-        comps_s = run_static_trace(model, params, trace, n_slots=slots,
-                                   max_len=max_len)
-        ts = percentile_table(comps_s, max(c.t_done for c in comps_s))
-        ts["mode"] = "static"
-        rows.append(ts)
     keys = ["mode", "requests", "done", "rejected", "expired", "failed",
             "tokens", "tok_per_s", "lat_p50_ms", "lat_p99_ms",
             "ttft_p50_ms", "ttft_p99_ms"]
-    log(format_table(rows, keys))
+    log(format_table([table], keys))
     return handles, table
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--sparsity", type=float, default=0.0)
     ap.add_argument("--expert-sparsity", type=float, default=0.0,
                     help="serve with a fraction of routed experts removed "
@@ -198,10 +129,9 @@ def main():
                     help="enc-dec only: fixed encoder-memory length; trace "
                          "requests carry synthetic frames of this length")
     ap.add_argument("--ckpt-in", default=None)
-    ap.add_argument("--trace", type=int, default=0,
+    ap.add_argument("--trace", type=int, required=True,
                     help="serve N synthetic ragged requests through the "
-                         "continuous-batching engine instead of the "
-                         "fixed-batch loop")
+                         "continuous-batching engine")
     ap.add_argument("--slots", type=int, default=4,
                     help="engine slots (concurrent requests)")
     ap.add_argument("--max-len", type=int, default=128,
@@ -213,9 +143,6 @@ def main():
     ap.add_argument("--rate", type=float, default=None,
                     help="trace arrival rate (req/s); default all at t=0")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--compare-static", action="store_true",
-                    help="also run the fixed-batch baseline on the same "
-                         "trace and print both rows")
     ap.add_argument("--queue-depth", type=int, default=16,
                     help="bounded admission queue beyond the slots; "
                          "requests past it are rejected (backpressure)")
@@ -281,26 +208,20 @@ def main():
         last = latest_step(args.ckpt_in)
         params, _ = restore_checkpoint(args.ckpt_in, last, params)
         print(f"[serve] loaded {args.ckpt_in} step {last}")
-    if args.trace > 0:
-        pr = tuple(int(x) for x in args.prompt_range.split(","))
-        gr = tuple(int(x) for x in args.gen_range.split(","))
-        dl = None if args.deadline_ms is None else \
-            tuple(float(x) for x in args.deadline_ms.split(","))
-        serve_trace(model, params, n=args.trace, slots=args.slots,
-                    max_len=args.max_len, prompt_range=pr, gen_range=gr,
-                    rate=args.rate, seed=args.seed,
-                    compare_static=args.compare_static,
-                    queue_depth=args.queue_depth, deadline_ms=dl,
-                    deadline_frac=args.deadline_frac,
-                    prefix_cache=args.prefix_cache,
-                    prefix_len=args.prefix_len, spf=args.spf,
-                    replicas=args.replicas, route=args.route,
-                    mem_len=args.mem_len, sharding=sharding,
-                    prefill_chunk=args.prefill_chunk)
-    else:
-        serve_loop(model, params, batch=args.batch,
-                   prompt_len=args.prompt_len, gen=args.gen,
-                   max_len=args.prompt_len + args.gen + 1)
+    pr = tuple(int(x) for x in args.prompt_range.split(","))
+    gr = tuple(int(x) for x in args.gen_range.split(","))
+    dl = None if args.deadline_ms is None else \
+        tuple(float(x) for x in args.deadline_ms.split(","))
+    serve_trace(model, params, n=args.trace, slots=args.slots,
+                max_len=args.max_len, prompt_range=pr, gen_range=gr,
+                rate=args.rate, seed=args.seed,
+                queue_depth=args.queue_depth, deadline_ms=dl,
+                deadline_frac=args.deadline_frac,
+                prefix_cache=args.prefix_cache,
+                prefix_len=args.prefix_len, spf=args.spf,
+                replicas=args.replicas, route=args.route,
+                mem_len=args.mem_len, sharding=sharding,
+                prefill_chunk=args.prefill_chunk)
 
 
 if __name__ == "__main__":

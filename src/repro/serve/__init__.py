@@ -6,8 +6,7 @@ front-end's queue/deadline/prefix-cache semantics.
 from repro.serve.cache import (RecurrentSlotCache, SlotCache, cache_bytes,
                                cache_contract)
 from repro.serve.engine import (Completion, Request, ServeEngine,
-                                run_static_trace, synthetic_trace,
-                                percentile_table)
+                                synthetic_trace, percentile_table)
 from repro.serve.errors import ERRORS
 from repro.serve.frontend import (AsyncServeFrontend, Handle, ServeFrontend,
                                   frontend_table)
@@ -20,7 +19,7 @@ from repro.serve.sharding import (ServeSharding, device_bytes_estimate,
 
 __all__ = ["SlotCache", "RecurrentSlotCache", "cache_bytes",
            "cache_contract", "ERRORS", "Request", "Completion",
-           "ServeEngine", "run_static_trace", "synthetic_trace",
+           "ServeEngine", "synthetic_trace",
            "percentile_table", "ServeFrontend", "AsyncServeFrontend",
            "Handle", "frontend_table", "PrefixCache", "AdmissionQueue",
            "Scheduler", "Overloaded", "Status", "ReplicaRouter",

@@ -25,8 +25,6 @@ until the next admit overwrites them.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
@@ -154,8 +152,8 @@ class SlotCache:
     def device_bytes(self) -> int:
         """Largest per-device resident bytes of the live cache — the number
         mesh sharding exists for (== ``bytes`` unsharded). Measured from
-        the arrays' addressable shards, like the calibration footprint
-        gate (benchmarks/bench_calib_sharded.py)."""
+        the arrays' addressable shards, as tests/test_sharded_calibration.py
+        measures the calibration statistics' footprint."""
         total = 0
         for leaf in jax.tree.leaves(self.cache):
             shards = getattr(leaf, "addressable_shards", None)
@@ -174,8 +172,8 @@ class RecurrentSlotCache(SlotCache):
     behind its ``pos`` mask, but a recurrent state is a lossy summary of the
     whole history with no mask to hide behind, so ``reset_slot`` scatters
     the empty-history (zero) state back into the lane. ``slot_bytes`` is
-    constant in ``max_len`` — the cheaper cache contract the recurrent
-    bench row gates (benchmarks/bench_serve.py).
+    constant in ``max_len`` — the cheaper cache contract
+    (tests/test_serve_zoo.py::test_zoo_recurrent_slot_bytes_constant).
     """
 
     def __init__(self, template_fn, n_slots: int, *, sharding=None,

@@ -17,9 +17,7 @@ One shared jitted decode step runs over all ``n_slots`` slots per iteration;
 per-slot ``pos`` valid-lengths inside the cache drive the masked decode
 attention (``kernels/flash_decode/decode_attention`` on TPU), so slots at
 different sequence positions coexist in one step. Finished requests retire
-and their slot is refilled immediately — no batch barrier, which is where
-the throughput win over static batching comes from on ragged traces
-(``benchmarks/bench_serve.py`` gates it).
+and their slot is refilled immediately — no batch barrier.
 
 Prompt-length bucketing bounds recompiles: prompts are right-padded to the
 next bucket and prefilled with per-sample true ``lengths`` (causal attention
@@ -626,81 +624,9 @@ class ServeEngine:
     @property
     def device_cache_bytes(self) -> int:
         """Largest per-device slot-cache footprint (== ``cache_bytes``
-        unsharded; ~1/model-axis of it under a ``sharding`` — the number
-        benchmarks/bench_serve_sharded.py gates)."""
+        unsharded; ~1/model-axis of it under a ``sharding`` —
+        tests/test_serve_sharded.py holds it to ``device_bytes_estimate``)."""
         return self.slotcache.device_bytes
-
-
-# ---------------------------------------------------------------------------
-# static fixed-batch baseline (the pre-engine serve loop, trace-shaped)
-# ---------------------------------------------------------------------------
-
-def run_static_trace(model, params, requests: List[Request], *,
-                     n_slots: int, max_len: int,
-                     buckets=None) -> List[Completion]:
-    """Serve the trace in fixed batches of ``n_slots``: each batch pads every
-    prompt to the longest and decodes until the *longest* generation in the
-    batch finishes — the batch barrier continuous batching removes."""
-    cfg = model.cfg
-    if set(cfg.layer_kinds) != {"attn"}:
-        raise ValueError(errors.msg("static_trace_ineligible"))
-    buckets = sorted(buckets) if buckets else default_buckets(max_len)
-    vocab = cfg.vocab_size
-
-    @jax.jit
-    def prefill(params, batch, lengths):
-        logits, cache = model.prefill(params, batch, max_len,
-                                      lengths=lengths)
-        return jnp.argmax(logits[:, -1, :vocab], -1).astype(jnp.int32), cache
-
-    @jax.jit
-    def decode(params, tok, cache):
-        logits, cache = model.decode_step(params, tok, cache)
-        return jnp.argmax(logits[:, -1, :vocab], -1).astype(jnp.int32), cache
-
-    order = sorted(requests, key=lambda r: (r.arrival, r.rid))
-    groups = [order[i:i + n_slots] for i in range(0, len(order), n_slots)]
-
-    def bucket_of(group):
-        Lmax = max(len(r.tokens) for r in group)
-        return next((b for b in buckets if b >= Lmax), Lmax)
-
-    # compile-warm every prefill bucket this trace will use (and the decode
-    # step) outside the timed region, matching the engine's warmup — the
-    # measured gap must be the batch barrier, not compile time
-    for L in sorted({bucket_of(g) for g in groups}):
-        tok, cache = prefill(params, {"tokens": jnp.zeros((n_slots, L),
-                                                          jnp.int32)},
-                             jnp.ones((n_slots,), jnp.int32))
-        decode(params, tok[:, None], cache)
-
-    done = []
-    t0 = time.perf_counter()
-    for group in groups:
-        while time.perf_counter() - t0 < max(r.arrival for r in group):
-            time.sleep(1e-4)               # batch can't start early
-        B = n_slots
-        L = bucket_of(group)
-        toks = np.zeros((B, L), np.int32)
-        lens = np.ones((B,), np.int32)
-        for j, r in enumerate(group):
-            toks[j, :len(r.tokens)] = r.tokens
-            lens[j] = len(r.tokens)
-        first, cache = prefill(params, {"tokens": jnp.asarray(toks)},
-                               jnp.asarray(lens))
-        outs = [[int(t)] for t in np.asarray(first)[:len(group)]]
-        tok = first
-        for _ in range(max(r.gen for r in group) - 1):
-            tok, cache = decode(params, tok[:, None], cache)
-            for j in range(len(group)):
-                outs[j].append(int(tok[j]))
-        t_done = time.perf_counter() - t0
-        for j, r in enumerate(group):      # everyone waits for the batch
-            done.append(Completion(
-                rid=r.rid, tokens=np.asarray(outs[j][:r.gen], np.int32),
-                prompt_len=len(r.tokens), arrival=r.arrival,
-                t_admit=t_done, t_first=t_done, t_done=t_done))
-    return sorted(done, key=lambda c: c.rid)
 
 
 # ---------------------------------------------------------------------------

@@ -35,9 +35,6 @@ ERRORS = {
         "{name}: prefix cache needs a replayable slot-cache contract "
         "(pure global-attention KV rewind, or whole-prefix recurrent "
         "state snapshots); serve without one",
-    "static_trace_ineligible":
-        "static ragged baseline needs a pure global-attention stack "
-        "(batched ragged prefill)",
     # mesh-sharded serving: every payload leaf of the slot cache must
     # split evenly over the model axis (never padded — docs/serving.md
     # "Mesh-sharded serving")

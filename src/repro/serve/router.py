@@ -31,9 +31,8 @@ admit time:
 
 One router ``decode_step`` steps every live replica **concurrently**
 (one thread per replica — each replica's jitted step holds no shared
-state, and device compute releases the GIL), which is where the fleet
-throughput win comes from: N replicas' decode steps cost one replica's
-wall time, gated >= 3x for N=4 in ``benchmarks/bench_serve.py``.
+state, and device compute releases the GIL), so N replicas' decode steps
+can overlap.
 
 Health: a replica whose ``decode_step``/``admit`` raises is marked DOWN.
 Its in-flight requests keep every token produced before the failing step

@@ -25,8 +25,8 @@ chunks and a long prompt never freezes their streams. Chunking changes
 *when* work happens, never *what* is computed: token streams are
 byte-identical to the unchunked engine on every slot-cache contract
 (docs/serving.md "Scheduler" carries the per-contract exactness argument;
-``benchmarks/bench_serve.py`` gates both the identity and the co-resident
-decode-gap p99 win).
+the identity is tested per contract in ``tests/test_serve.py``,
+``tests/test_serve_frontend.py`` and ``tests/test_serve_zoo.py``).
 
 ``prefill_chunk=None`` (the default) is the atomic policy: ``start`` runs
 the engine's one-shot ``admit`` to completion, byte-for-byte the pre-PR-10

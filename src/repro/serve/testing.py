@@ -1,4 +1,4 @@
-"""Pure-Python fake engines for scheduler/router tests and CPU benches.
+"""Pure-Python fake engines for scheduler/router tests.
 
 ``FleetFakeEngine`` exposes exactly the engine-agnostic slot surface the
 front-end and ``ReplicaRouter`` consume (``free_slots`` / ``admit`` /
@@ -29,13 +29,10 @@ Two properties matter for fleet tests:
 
 Fault injection: set ``fail_next_admit = True`` to make the next admit
 raise (death during prefill), ``fail_next_decode = True`` for death
-mid-decode. ``step_time`` adds a per-``decode_step`` sleep (the whole
-fused step, lanes in parallel) so FakeEngine-backed throughput benches
-model a fleet of fixed-cost decode steps.
+mid-decode.
 """
 from __future__ import annotations
 
-import time
 from typing import List, Optional
 
 from repro.serve import errors
@@ -72,15 +69,13 @@ class _FakeCfg:
 
 class FleetFakeEngine:
     """Engine-surface fake: one ``decode_step`` = one token per active
-    slot, ``step_time`` seconds of (GIL-releasing) wall time per step."""
+    slot."""
 
     cfg = _FakeCfg()
     contract = "kv"                # slot-cache contract (docs/serving.md)
 
-    def __init__(self, n_slots: int, *, step_time: float = 0.0,
-                 prefix_ok: bool = False):
+    def __init__(self, n_slots: int, *, prefix_ok: bool = False):
         self.n_slots = n_slots
-        self.step_time = step_time
         self._prefix_ok = prefix_ok
         self.slots = [_FakeSlot() for _ in range(n_slots)]
         self.stats = {"admits": 0, "decode_steps": 0, "cancels": 0,
@@ -173,8 +168,6 @@ class FleetFakeEngine:
         if self.fail_next_decode:
             self.fail_next_decode = False
             raise RuntimeError("injected decode failure")
-        if self.step_time:
-            time.sleep(self.step_time)             # releases the GIL
         self.stats["decode_steps"] += 1
         retired = []
         for i, s in enumerate(self.slots):

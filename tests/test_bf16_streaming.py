@@ -1,6 +1,6 @@
 """bf16 activation streaming (`stats_dtype="bfloat16"`): Sigma tolerance on
-ill-conditioned inputs, engine parity, fingerprint separation, and the
-zero-sparsity pipeline oracle under the bf16 stream.
+ill-conditioned inputs, engine parity, the halved tap bytes, fingerprint
+separation, and the zero-sparsity pipeline oracle under the bf16 stream.
 
 The invariant: every statistic ACCUMULATES fp32 regardless of the streaming
 dtype — bf16 only rounds each tapped activation once (8-bit mantissa,
@@ -117,6 +117,29 @@ def test_engine_bf16_stream_parity(arch):
                     <= max(tol_na, 1.0), (u.name, key)
             else:
                 assert _relerr(a, b) <= 2 * TOL, (u.name, key)
+        if "s2" in s32[u.name]:     # Sigma itself: the documented TOL
+            assert _relerr(s32[u.name]["s2"], s16[u.name]["s2"]) <= TOL, \
+                u.name
+
+
+@pytest.mark.parametrize("arch", ["deit-base", "granite-8b",
+                                  "qwen3-moe-235b-a22b"])
+def test_bf16_stream_halves_tap_bytes(arch):
+    """What the bf16 stream is for: one calibration batch's activation
+    taps take at least 1.9x fewer bytes than under the fp32 stream (~2x;
+    an MoE's routing mask stays fp32)."""
+    cfg = tiny_cfg(arch)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    batch = next(iter(calib_factory(cfg, n=1)()))
+
+    def tap_bytes(dtype):
+        taps = {}
+        with model_common.tap_dtype(dtype):
+            model.apply(params, batch, taps=taps)
+        return sum(t.size * t.dtype.itemsize for t in jax.tree.leaves(taps))
+
+    assert tap_bytes(jnp.float32) / tap_bytes(jnp.bfloat16) >= 1.9
 
 
 def test_engine_fingerprint_includes_stats_dtype():

@@ -35,7 +35,7 @@ def test_choices_valid(n, f, dtype):
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_never_predicted_slower_than_fixed_defaults(n, f, dtype):
     """(128, 512) is in the candidate set, so the argmin choice can never
-    be predicted slower — the bench_calibration.py gate in miniature."""
+    be predicted slower than the fixed legacy tiles."""
     bf, bn = autotune.choose_tiles(n, f, dtype)
     assert autotune.predicted_time(n, f, dtype, bf, bn) <= \
         autotune.predicted_time(n, f, dtype, 128, 512)

@@ -10,7 +10,6 @@ For every assigned family (reduced config): prune at 50%/50%, assert
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -65,8 +64,8 @@ def test_prune_end_to_end(arch):
     # objective — asserted strictly above (j_star <= j_uncomp per unit).
     # End-to-end output MSE through the inter-layer nonlinearities can
     # wobble a few percent on random-init weights with a tiny calibration
-    # set (no real redundancy to exploit); trained-model benchmarks show
-    # the expected end-to-end gains (EXPERIMENTS.md fig2).
+    # set (no real redundancy to exploit); on a trained model
+    # examples/quickstart.py shows the end-to-end gain over rank-only.
     assert errs[True] <= errs[False] * 1.25, \
         f"compensation should not hurt: {errs}"
 

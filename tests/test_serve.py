@@ -3,7 +3,7 @@
 Covers: prefill+decode == full-sequence-forward parity (LM and enc-dec),
 zero-sparsity pruned serving token-identity through the engine, slot
 admit/retire/refill correctness on a ragged trace, pruned cache shrinkage,
-ragged-prefill soundness, and the serve_loop token off-by-one regression.
+ragged-prefill soundness, and chunked prefill.
 """
 from __future__ import annotations
 
@@ -201,37 +201,6 @@ def test_pruned_config_shrinks_cache():
               jax.tree_util.tree_flatten_with_path(pruned.slotcache.cache)[0]
               if any(getattr(p, "key", None) == "k" for p in path)}
     assert k_dims == {pcfg.eff_qk}
-
-
-# ---------------------------------------------------------------------------
-# serve_loop regression (token off-by-one)
-# ---------------------------------------------------------------------------
-
-def test_serve_loop_returns_exactly_gen_matching_tokens(trained_lm):
-    """serve_loop must return exactly ``gen`` tokens and every one of them
-    must match the full-sequence model.apply argmax rollout — the old loop
-    ran one extra decode step and discarded its token, shifting the stream
-    off the timed region."""
-    from repro.launch.serve import serve_loop
-    cfg, model, params = trained_lm
-    batch, prompt_len, gen, seed = 2, 12, 6, 0
-    out, t_prefill, t_decode = serve_loop(
-        model, params, batch=batch, prompt_len=prompt_len, gen=gen,
-        max_len=prompt_len + gen + 1, seed=seed, log=lambda *a: None)
-    assert out.shape == (batch, gen)
-    # reconstruct serve_loop's prompt and greedy-roll the full forward
-    rng = np.random.RandomState(seed)
-    toks = rng.randint(0, cfg.vocab_size,
-                       size=(batch, prompt_len)).astype(np.int32)
-    seq = jnp.asarray(toks)
-    for t in range(gen):
-        logits = model.apply(params, {"tokens": seq})[0]
-        nxt = jnp.argmax(logits[:, -1, : cfg.vocab_size],
-                         -1)[:, None].astype(jnp.int32)
-        np.testing.assert_array_equal(np.asarray(out[:, t]),
-                                      np.asarray(nxt[:, 0]), f"step {t}")
-        seq = jnp.concatenate([seq, nxt], axis=1)
-    assert t_prefill > 0 and t_decode > 0
 
 
 # ---------------------------------------------------------------------------

@@ -385,16 +385,18 @@ def test_frontend_table_counts(lm):
 
 
 # ---------------------------------------------------------------------------
-# engine surfaces the serve suites previously left to the benchmarks
+# engine surfaces: the trace runner's reporting, warmup, guards
 # ---------------------------------------------------------------------------
 
 def test_static_trace_runner_and_percentiles(lm):
-    from repro.serve import percentile_table, run_static_trace
+    """``ServeEngine.run`` over a whole request list returns completions
+    in rid order, and ``percentile_table``/``format_table`` report them."""
+    from repro.serve import percentile_table
     from repro.serve.engine import format_table
     model, params = lm
     trace = synthetic_trace(5, model.cfg.vocab_size, seed=4,
                             prompt_range=(4, 10), gen_range=(2, 5))
-    comps = run_static_trace(model, params, trace, n_slots=2, max_len=48)
+    comps = _engine(lm).run(trace)
     assert [c.rid for c in comps] == sorted(r.rid for r in trace)
     tab = percentile_table(comps, max(c.t_done for c in comps))
     assert tab["requests"] == 5

@@ -25,9 +25,11 @@ import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from helpers import tiny_cfg
+from repro.configs import get_config
 from repro.models import build_model
 from repro.serve import (ServeEngine, cache_contract, device_bytes_estimate,
                          slot_specs)
@@ -163,12 +165,44 @@ def test_device_bytes_estimate_splits_payload_only():
     assert est < total
 
 
+@pytest.mark.parametrize("arch,pruning_shrinks", [
+    ("jamba-1.5-large-398b", True), ("deepseek-v3-671b", False)])
+def test_published_width_cache_splits_per_device(arch, pruning_shrinks):
+    """At published widths (8 slots x 4096 positions, eval_shape templates,
+    nothing allocated) a (2 data x 8 model) mesh leaves each device ~1/16
+    of the slot cache. 50% CORP pruning shrinks the hybrid's per-device
+    cache further (eff_qk halves the K rows, d_inner_kept the SSM state);
+    the MLA latent cache does not depend on eff_qk, so it stays put."""
+    from repro.serve.cache import _infer_batch_axes, cache_bytes
+    mesh = {"data": 2, "model": 8}
+
+    def footprint(cfg):
+        model = build_model(cfg)
+        aparams = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
+
+        def template(b):
+            req = {"tokens": jax.ShapeDtypeStruct((b, 8), jnp.int32)}
+            return jax.eval_shape(
+                lambda p, r: model.prefill(p, r, 4096)[1], aparams, req)
+
+        t = template(8)
+        specs = slot_specs(t, _infer_batch_axes(template(1), template(2)),
+                           mesh, name=cfg.name, kv_heads=cfg.n_kv_heads)
+        return cache_bytes(t), device_bytes_estimate(t, specs, mesh)
+
+    total, dense = footprint(get_config(arch))
+    assert abs(dense - total / 16) <= 0.02 * total / 16, (dense, total)
+    _, pruned = footprint(get_config(arch).pruned(0.5, 0.5))
+    assert (pruned < dense) if pruning_shrinks else (pruned == dense), \
+        (pruned, dense)
+
+
 def test_degenerate_mesh_is_identity():
     """A (1, 1) mesh runs the entire sharded code path — param placement,
     spec'd cache allocation, pinned out_shardings on decode/prefill/write
     — on the suite's single device, and must stream exactly like the
     unsharded engine (the live multi-device version of this parity is
-    the subprocess test below and benchmarks/bench_serve_sharded.py)."""
+    the subprocess test below)."""
     from repro.launch.mesh import make_mesh
     from repro.serve import ServeSharding, synthetic_trace
     cfg, ref_eng = _engine("deepseek-7b")
@@ -201,7 +235,8 @@ def test_sharded_engine_token_parity_and_leaf_placement():
     mesh: sharded streams token-identical to single-device (6 requests
     through 2 slots, so retire/refill happens mid-flight on sharded
     state), every live global-cache leaf carries exactly its slot_specs
-    placement, and measured per-device bytes == the analytic estimate."""
+    placement, measured per-device bytes == the analytic estimate, and a
+    50%-pruned kv config's per-device cache is below the dense one's."""
     out = run_py("""
 import dataclasses
 import jax, numpy as np
@@ -249,6 +284,17 @@ for arch in ("deepseek-7b", "rwkv6-3b", "deepseek-v3-671b"):
         (arch, shard.device_cache_bytes, est)
     assert single.cache_bytes / shard.device_cache_bytes >= 3.6
     print(arch, "OK")
+
+# CORP pruning composes with the split: the 50%-pruned kv config's
+# per-device cache sits strictly below the dense one's (eff_qk on top of
+# the 1/model split)
+def device_bytes(cfg):
+    model = build_model(cfg)
+    return ServeEngine(model, model.init(jax.random.PRNGKey(0)), n_slots=2,
+                       max_len=32, sharding=sharding).device_cache_bytes
+
+cfg = zoo("deepseek-7b")
+assert device_bytes(cfg.pruned(0.5, 0.5)) < device_bytes(cfg)
 print("OK")
 """)
     assert out.count("OK") == 4

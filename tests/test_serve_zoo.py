@@ -283,8 +283,8 @@ def test_zoo_recurrent_slot_bytes_constant(zoo, arch):
     """Recurrent slot state is O(1) in sequence budget: doubling max_len
     must not grow a pure-recurrent stack's per-slot bytes at all, and a
     hybrid's (jamba: attn rows still grow) strictly slower than a
-    pure-KV stack's — the serving win the recurrent contract buys;
-    bench_serve gates the same invariant with a KV reference column."""
+    pure-KV stack's, which grows — the serving win the recurrent
+    contract buys."""
     model, params = zoo(arch)
     small = _engine(model, params, max_len=32)
     large = _engine(model, params, max_len=64)
@@ -298,6 +298,33 @@ def test_zoo_recurrent_slot_bytes_constant(zoo, arch):
         assert growth == 1.0                      # no KV rows at all
     assert growth < kv_growth                     # strictly sublinear
     assert kv_growth > 1.5                        # the KV reference grows
+
+
+@pytest.mark.parametrize("arch", ["rwkv6-3b", "qwen3-moe-235b-a22b",
+                                  "seamless-m4t-large-v2"])
+def test_zoo_chunked_prefill_matches_atomic(zoo, arch):
+    """Chunked prefill changes no token under the recurrent, MoE and
+    enc-dec contracts (the kv case is test_serve.py's
+    test_chunked_prefill_token_identical): the engine's chunked runner
+    and the chunked front-end both stream byte-identically to atomic
+    admits, with prompts several chunks long and slots refilling."""
+    model, params = zoo(arch)
+    cfg = model.cfg
+    rng = np.random.RandomState(9)
+    kw = {}
+    if cfg.family == "encdec":
+        kw["frames"] = rng.randn(MEM_LEN, cfg.d_model).astype(np.float32)
+    reqs = [Request(rid=i, tokens=rng.randint(
+        0, cfg.vocab_size, size=p).astype(np.int32), gen=g, **kw)
+        for i, (p, g) in enumerate([(12, 3), (5, 4), (9, 2), (4, 3)])]
+    ref = [c.tokens.tolist()
+           for c in _engine(model, params, n_slots=2).run(reqs)]
+    chunked = _engine(model, params, n_slots=2).run(reqs, prefill_chunk=3)
+    assert [c.tokens.tolist() for c in chunked] == ref
+    handles = ServeFrontend(_engine(model, params, n_slots=2),
+                            queue_depth=len(reqs), prefill_chunk=3).run(reqs)
+    assert all(h.status is Status.DONE for h in handles)
+    assert [h.tokens for h in handles] == ref
 
 
 @pytest.mark.parametrize("arch", DEIT_IDS[:1])

@@ -39,7 +39,9 @@ def run_py(code: str, devices: int = 4, timeout: int = 560):
 def test_sharded_engine_stat_parity_and_specs():
     """Sharded stats == single-device stats (both passes, fp32 allclose) on
     a forced 4-device (2 data x 2 model) mesh, with every dense unit's s2
-    column-sharded — the addressable shard holds F/m columns, never F."""
+    column-sharded — the addressable shard holds F/m columns, never F, so
+    a device holds fewer statistic bytes than the unsharded engine — and
+    the sharded bf16 stream's Sigma within tolerance of the fp32 one."""
     out = run_py("""
 import jax, numpy as np
 from repro.core import CalibrationEngine, discover_units
@@ -79,6 +81,20 @@ for arch in ("deit-base", "granite-8b"):
         assert local[-1] == a.shape[-1] // 2, (u.name, a.shape, local)
         checked += 1
     assert checked, arch
+    # so each device holds fewer statistic bytes than one unsharded copy
+    per_dev = sum(max(s.data.nbytes for s in x.addressable_shards)
+                  for x in jax.tree.leaves(acc))
+    assert per_dev < sum(x.nbytes for x in jax.tree.leaves(acc)), arch
+
+    # the bf16 stream composes with sharding: Sigma stays within the
+    # documented tolerance (test_bf16_streaming.TOL) of the fp32 stream
+    s16 = CalibrationEngine(model, units, phase=1, mesh=mesh,
+                            stats_dtype="bfloat16").run(params, calib())
+    for u in units:
+        if "s2" in sh[u.name]:
+            a = np.asarray(sh[u.name]["s2"], np.float64)
+            b = np.asarray(s16[u.name]["s2"], np.float64)
+            assert np.max(np.abs(a - b)) <= 1e-2 * np.max(np.abs(a)), u.name
 
     # pass 2 parity (ridge-system inputs; complex classes on granite)
     plan = {}
